@@ -32,6 +32,7 @@ import time
 import traceback
 
 from benchmarks.common import N_SIM_REQUESTS, compile_monitor
+from repro import compile_cache
 
 BENCHES = [
     "replay_bench",  # py_ref loop vs compiled replay fast path
@@ -85,6 +86,7 @@ def main() -> None:
     if unknown:
         sys.exit(f"unknown benchmark(s) {unknown}; choose from {BENCHES}")
 
+    compile_cache.enable()
     failures: dict[str, str] = {}
     bench_seconds = {}
     bench_timings = {}

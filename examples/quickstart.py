@@ -15,9 +15,12 @@ the same trace and coin streams.
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core import build
 from repro.core.harness import measure_cache, run_cache_trace, zipf_trace
 from repro.core.simulator import simulate_network
+
+compile_cache.enable()
 
 P = np.array([0.5, 0.7, 0.85, 0.95, 0.99])
 

@@ -23,15 +23,23 @@ what both the batched ``lax.scan`` twin and the Pallas kernel need (and
 measured on the 8-capacity x 60k-request grid the masked-argmin scan
 already beats the dlist scan on CPU).
 
-Every policy is a pure step with one uniform signature::
+Every policy is a step with one uniform signature::
 
     state, hit, evicted, ops = FLAT_STEPS[policy](state, key, u, p, q)
 
-over a single :class:`FlatState` pytree whose fields are fixed across
-policies (unused fields ride along at zero cost inside a fused scan), an
-``int32[N_PARAMS]`` per-lane parameter vector ``p`` and a scalar float
-coin threshold ``q``.  Capacity-derived parameters are *traced* per-lane
-values, so one compiled program serves the whole (capacity x seed) grid.
+written once against the indexed-state interface of
+:mod:`repro.indexed_state`: it reads and writes single slots of named
+tables (``K2S``, ``S2K``, ``TS``, ``BIT``, ``AUX``, ``GHOST``, ``REGS``) and
+runs masked argmins over the slot tables.  The twin passes an
+:class:`~repro.indexed_state.ArrayState` (jnp arrays, functional updates),
+the Pallas kernel a :class:`~repro.kernels.state.RefState` (scratch refs,
+in-place stores of the touched slots), so both execute the same policy
+code.  ``p`` is the per-lane ``int32[N_PARAMS]`` parameter vector (indexed
+by the static ``P_*`` constants, so a tuple of scalars works too) and
+``q`` the scalar float coin threshold.  Capacity-derived parameters are
+*traced* per-lane values, so one compiled program serves the whole
+(capacity x seed) grid.  ``ops`` is a (delink, head, tail, scan) tuple of
+int32 scalars.
 
 Bit-identity with :mod:`repro.cache.policies` (and therefore with the
 ``py_ref`` oracles) is pinned by ``tests/test_pallas_replay.py``: hits,
@@ -40,16 +48,16 @@ evicted keys and op vectors must match element-wise, padded and exact.
 
 from __future__ import annotations
 
-from typing import Any, Callable, Dict, NamedTuple, Tuple
+from typing import Any, Callable, Dict, Sequence, Tuple
 
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+
+from repro.indexed_state import INT32_MAX, ArrayState, i32
 
 # numpy scalars, not jnp: the Pallas kernel body closes over these, and a
 # jnp scalar would be a captured device constant (pallas_call rejects those)
 NIL = np.int32(-1)
-_INT32_MAX = np.int32(2**31 - 1)
 # bias for collapsing a cyclic hand scan into one argmin (see _sieve_step);
 # timestamps stay far below this (at most a couple of bumps per request)
 _WRAP_BIAS = np.int32(2**30)
@@ -75,14 +83,14 @@ N_PARAMS = 6
 
 # Packed op-vector bit layout (delink, head, tail, scan) -> one int32.
 # head is bounded by max_scan + 2 per access, tail by 2, scan by the
-# capacity (SIEVE's hand walk); 19 bits cover every capacity in the
-# benchmarks with room to spare.
+# capacity (SIEVE's hand walk); the 20 scan bits reach into the sign bit
+# and cover every capacity below 2**20.
 _OPS_HEAD_SHIFT = 1
 _OPS_TAIL_SHIFT = 9
 _OPS_SCAN_SHIFT = 12
 _OPS_HEAD_MASK = 0xFF      # 8 bits
 _OPS_TAIL_MASK = 0x7       # 3 bits
-_OPS_SCAN_MASK = 0x7FFFF   # 19 bits
+_OPS_SCAN_MASK = 0xFFFFF   # 20 bits
 
 _PARAM_NAMES = {
     "lru": (),
@@ -95,36 +103,32 @@ _PARAM_NAMES = {
 }
 
 
-class FlatState(NamedTuple):
-    """Uniform flat policy state (all int32; booleans stored as 0/1).
+# -- table names (the indexed-state layout every step is written against) ---
+K2S = "key2slot"   # (K,) slot of each key, NIL when absent
+S2K = "slot2key"   # (P,) key in each slot, NIL when free
+TS = "ts"          # (P,) push timestamp (list position)
+BIT = "bit"        # (P,) CLOCK/SIEVE/S3 reference bit
+AUX = "aux"        # (P,) secondary membership bit (SLRU in_T, S3 in_M)
+GHOST = "ghost"    # (P,) evicted-key ring (S3-FIFO), NIL-filled
+REGS = "regs"      # (N_REGS,) scalar registers (see the R_* indices)
 
-    ``aux`` is the policy's second membership bit: ``in_T`` for SLRU,
-    ``in_M`` for S3-FIFO, unused elsewhere.  ``ghost`` is the S3-FIFO
-    ghost ring (NIL-filled for other policies).  ``regs`` packs the
-    scalar registers (see the ``R_*`` indices).
-    """
-
-    key2slot: jnp.ndarray   # (K,) slot of each key, NIL when absent
-    slot2key: jnp.ndarray   # (P,) key in each slot, NIL when free
-    ts: jnp.ndarray         # (P,) push timestamp (list position)
-    bit: jnp.ndarray        # (P,) CLOCK/SIEVE/S3 reference bit
-    aux: jnp.ndarray        # (P,) secondary membership bit
-    ghost: jnp.ndarray      # (P,) evicted-key ring (S3-FIFO)
-    regs: jnp.ndarray       # (N_REGS,) scalar registers
+# the slot-indexed tables: the vector group the masked argmins walk
+SLOT_TABLES = (S2K, TS, BIT, AUX, GHOST)
 
 
-def flat_state_init(key_space: int, pad: int) -> FlatState:
-    """Zero state shared by every policy (SIEVE's hand starts at NIL)."""
+def flat_state_init(key_space: int, pad: int) -> ArrayState:
+    """Zero state shared by every policy (SIEVE's hand starts at NIL),
+    as the twin's array-backed indexed state."""
     regs = jnp.zeros((N_REGS,), jnp.int32).at[R_HAND].set(NIL)
-    return FlatState(
-        key2slot=jnp.full((key_space,), NIL, jnp.int32),
-        slot2key=jnp.full((pad,), NIL, jnp.int32),
-        ts=jnp.zeros((pad,), jnp.int32),
-        bit=jnp.zeros((pad,), jnp.int32),
-        aux=jnp.zeros((pad,), jnp.int32),
-        ghost=jnp.full((pad,), NIL, jnp.int32),
-        regs=regs,
-    )
+    return ArrayState(tabs={
+        K2S: jnp.full((key_space,), NIL, jnp.int32),
+        S2K: jnp.full((pad,), NIL, jnp.int32),
+        TS: jnp.zeros((pad,), jnp.int32),
+        BIT: jnp.zeros((pad,), jnp.int32),
+        AUX: jnp.zeros((pad,), jnp.int32),
+        GHOST: jnp.full((pad,), NIL, jnp.int32),
+        REGS: regs,
+    }, vec=TS)
 
 
 def flat_lane_params(policy: str, capacity: int,
@@ -161,13 +165,14 @@ def flat_lane_params(policy: str, capacity: int,
     return vec, q
 
 
-def pack_ops(ops: jnp.ndarray) -> jnp.ndarray:
-    """Pack an int32[4] (delink, head, tail, scan) op vector into one int32."""
+def pack_ops(ops: Sequence[Any]) -> jnp.ndarray:
+    """Pack a (delink, head, tail, scan) op tuple into one int32."""
+    delink, head, tail, scan = (i32(o) for o in ops)
     return (
-        ops[0]
-        | (ops[1] << _OPS_HEAD_SHIFT)
-        | (ops[2] << _OPS_TAIL_SHIFT)
-        | (ops[3] << _OPS_SCAN_SHIFT)
+        delink
+        | (head << _OPS_HEAD_SHIFT)
+        | (tail << _OPS_TAIL_SHIFT)
+        | (scan << _OPS_SCAN_SHIFT)
     ).astype(jnp.int32)
 
 
@@ -185,85 +190,85 @@ def unpack_ops(packed: jnp.ndarray) -> jnp.ndarray:
     )
 
 
-def _i32(x: Any) -> jnp.ndarray:
-    return jnp.asarray(x).astype(jnp.int32)
+Ops = Tuple[Any, Any, Any, Any]   # (delink, head, tail, scan) int32 scalars
 
 
 def _ops4(delink: Any = 0, head: Any = 0, tail: Any = 0,
-          scan: Any = 0) -> jnp.ndarray:
-    return jnp.stack([_i32(delink), _i32(head), _i32(tail), _i32(scan)])
+          scan: Any = 0) -> Ops:
+    return (i32(delink), i32(head), i32(tail), i32(scan))
 
 
-def _min_slot(ts: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:
-    """Slot with minimum ts among ``mask`` — the masked list's tail."""
-    return jnp.argmin(jnp.where(mask, ts, _INT32_MAX)).astype(jnp.int32)
+def _ops_add(a: Ops, b: Ops) -> Ops:
+    return (a[0] + b[0], a[1] + b[1], a[2] + b[2], a[3] + b[3])
 
 
-def _toward_head(ts: jnp.ndarray, mask: jnp.ndarray,
-                 h: jnp.ndarray) -> jnp.ndarray:
-    """The list neighbour of ``h`` one step toward the head (NIL at head)."""
-    above = mask & (ts > ts[h])
-    return jnp.where(jnp.any(above), _min_slot(ts, above), NIL)
+def _occ(v: Any) -> Any:
+    return v.slot2key != NIL
 
 
-def _occupied(st: FlatState) -> jnp.ndarray:
-    return st.slot2key != NIL
+def _in_aux(v: Any) -> Any:
+    return _occ(v) & (v.aux != 0)
 
 
-def _clear_key(key2slot: jnp.ndarray, old_key: jnp.ndarray) -> jnp.ndarray:
+def _not_aux(v: Any) -> Any:
+    return _occ(v) & (v.aux == 0)
+
+
+def _min_slot(st: Any, mask: Callable[[Any], Any]) -> Tuple[Any, Any]:
+    """Slot with minimum ts among ``mask`` — the masked list's tail — and
+    that ts (``INT32_MAX`` when the mask is empty)."""
+    return st.argmin(lambda v: (mask(v), v.ts))
+
+
+def _clear_key(st: Any, old_key: Any) -> Any:
     """``_table_evict``'s guarded mapping clear (no-op when old_key is NIL)."""
-    return jnp.where(
-        old_key == NIL,
-        key2slot,
-        key2slot.at[jnp.maximum(old_key, 0)].set(NIL),
-    )
+    return st.set_if(K2S, jnp.maximum(old_key, 0), old_key != NIL, NIL)
+
+
+def _keep(st: Any, *outs: Any) -> Tuple[Any, Any]:
+    return st, outs
 
 
 # ---------------------------------------------------------------------------
-# LRU family (LRU / FIFO / Prob-LRU) — branch-free, mirrors
-# policies._list_cache_access scatter for scatter.
+# LRU family (LRU / FIFO / Prob-LRU) — mirrors
+# policies._list_cache_access write for write.
 # ---------------------------------------------------------------------------
 
 
-def _make_list_step(reorder_of: Callable[[jnp.ndarray, jnp.ndarray],
-                                         jnp.ndarray]):
-    def step(st: FlatState, key: jnp.ndarray, u: jnp.ndarray,
-             p: jnp.ndarray, q: jnp.ndarray):
-        slot = st.key2slot[key]
+def _make_list_step(reorder_of: Callable[[Any, Any], Any]):
+    def step(st: Any, key: Any, u: Any, p: Any, q: Any):
+        slot = st.get(K2S, key)
         hit = slot != NIL
         reorder = reorder_of(u, q)
         miss = ~hit
-        size = st.regs[R_SIZE]
-        now = st.regs[R_NOW]
+        size = st.get(REGS, R_SIZE)
+        now = st.get(REGS, R_NOW)
         cap = p[P_CAP]
         full = size >= cap
         evict = miss & full
-        victim = _min_slot(st.ts, _occupied(st))
+        st, victim = st.cond(evict, lambda st: (st, _min_slot(st, _occ)[0]),
+                             lambda st: (st, NIL))
         s = jnp.where(hit, slot, jnp.where(full, victim, size))
-        old_key = st.slot2key[s]
+        old_key = st.get(S2K, s)
         evicted = jnp.where(evict, old_key, NIL)
         idx_clear = jnp.where(evict, jnp.maximum(old_key, 0), key)
-        k2s = st.key2slot.at[idx_clear].set(
-            jnp.where(miss, NIL, st.key2slot[idx_clear])
-        )
-        k2s = k2s.at[key].set(jnp.where(miss, s, k2s[key]))
-        s2k = st.slot2key.at[s].set(jnp.where(miss, key, st.slot2key[s]))
+        st = st.set_if(K2S, idx_clear, miss, NIL)
+        st = st.set_if(K2S, key, miss, s)
+        st = st.set_if(S2K, s, miss, key)
         act = miss | (hit & reorder)
-        ts = st.ts.at[s].set(jnp.where(act, now, st.ts[s]))
-        regs = st.regs.at[R_SIZE].set(
-            jnp.minimum(size + miss.astype(jnp.int32), cap)
-        )
-        regs = regs.at[R_NOW].set(now + act.astype(jnp.int32))
+        st = st.set_if(TS, s, act, now)
+        st = st.set(REGS, R_SIZE, jnp.minimum(size + i32(miss), cap))
+        st = st.set(REGS, R_NOW, now + i32(act))
         ops = _ops4(delink=hit & reorder, head=act, tail=evict)
-        st = st._replace(key2slot=k2s, slot2key=s2k, ts=ts, regs=regs)
         return st, hit, evicted, ops
 
     return step
 
 
-_lru_step = _make_list_step(lambda u, q: jnp.bool_(True))
-_fifo_step = _make_list_step(lambda u, q: jnp.bool_(False))
-_prob_lru_step = _make_list_step(lambda u, q: jnp.float32(u) >= q)
+_lru_step = _make_list_step(lambda u, q: np.bool_(True))
+_fifo_step = _make_list_step(lambda u, q: np.bool_(False))
+_prob_lru_step = _make_list_step(
+    lambda u, q: jnp.asarray(u, jnp.float32) >= q)
 
 
 # ---------------------------------------------------------------------------
@@ -271,79 +276,77 @@ _prob_lru_step = _make_list_step(lambda u, q: jnp.float32(u) >= q)
 # ---------------------------------------------------------------------------
 
 
-def _clock_scan_evict(ts: jnp.ndarray, bit: jnp.ndarray, now: jnp.ndarray,
-                      mask: jnp.ndarray, max_scan: jnp.ndarray):
+def _clock_scan_evict(st: Any, mask: Callable[[Any], Any], max_scan: Any):
     """Shared CLOCK/S3-M eviction scan over a fixed membership mask.
 
     The victim stays *in* the mask for the whole loop (the dlist code only
     pops it as the loop's final act), so the mask never changes — only the
-    timestamps of reinserted slots move.  Returns
-    (ts, bit, now, victim, n_reinsert).
+    timestamps of reinserted slots move.  Advances ``R_NOW`` past the
+    reinsertions; returns (st, victim, n_reinsert).
     """
 
-    def cond(carry):
-        _, _, _, scans, done, _ = carry
+    def cond(st: Any, carry: Any):
+        _, scans, done, _ = carry
         return (~done) & (scans <= max_scan)
 
-    def body(carry):
-        ts, bit, now, scans, done, victim = carry
-        s = _min_slot(ts, mask)
-        give_chance = (bit[s] != 0) & (scans < max_scan)
-        ts = ts.at[s].set(jnp.where(give_chance, now, ts[s]))
-        bit = bit.at[s].set(jnp.where(give_chance, 0, bit[s]))
-        now = now + give_chance.astype(jnp.int32)
-        return (ts, bit, now, scans + 1, ~give_chance,
-                jnp.where(give_chance, victim, s))
+    def body(st: Any, carry: Any):
+        now, scans, done, victim = carry
+        s, _ = _min_slot(st, mask)
+        give_chance = (st.get(BIT, s) != 0) & (scans < max_scan)
+        st = st.set_if(TS, s, give_chance, now)
+        st = st.set_if(BIT, s, give_chance, 0)
+        return st, (now + i32(give_chance), scans + 1, ~give_chance,
+                    jnp.where(give_chance, victim, s))
 
-    ts, bit, now, scans, _, victim = lax.while_loop(
+    st, (now, scans, _, victim) = st.while_loop(
         cond, body,
-        (ts, bit, now, jnp.int32(0), jnp.bool_(False), NIL),
+        (st.get(REGS, R_NOW), np.int32(0), np.bool_(False), NIL),
     )
-    return ts, bit, now, victim, scans - 1
+    st = st.set(REGS, R_NOW, now)
+    return st, victim, scans - 1
 
 
-def _clock_step(st: FlatState, key: jnp.ndarray, u: jnp.ndarray,
-                p: jnp.ndarray, q: jnp.ndarray):
+def _fill(st: Any, key: Any, new_slot: Any, cap: Any) -> Any:
+    """Push ``key`` into ``new_slot`` at the list head with a clear bit."""
+    now = st.get(REGS, R_NOW)
+    size = st.get(REGS, R_SIZE)
+    st = st.set(K2S, key, new_slot)
+    st = st.set(S2K, new_slot, key)
+    st = st.set(TS, new_slot, now)
+    st = st.set(BIT, new_slot, 0)
+    st = st.set(REGS, R_NOW, now + 1)
+    return st.set(REGS, R_SIZE, jnp.minimum(size + 1, cap))
+
+
+def _fresh(st: Any):
+    """The next never-used slot while the cache fills (no eviction)."""
+    return st, (st.get(REGS, R_SIZE), NIL, _ops4())
+
+
+def _set_bit(st: Any, slot: Any):
+    return st.set(BIT, jnp.maximum(slot, 0), 1), (NIL, _ops4())
+
+
+def _clock_step(st: Any, key: Any, u: Any, p: Any, q: Any):
     del u, q
-    slot = st.key2slot[key]
+    slot = st.get(K2S, key)
     hit = slot != NIL
     cap = p[P_CAP]
 
-    def on_hit(st: FlatState):
-        bit = st.bit.at[jnp.maximum(slot, 0)].set(1)
-        return st._replace(bit=bit), NIL, _ops4()
+    def on_miss(st: Any):
+        def evict(st: Any):
+            st, victim, n_re = _clock_scan_evict(st, _occ, p[P_MAX_SCAN])
+            old_key = st.get(S2K, victim)
+            st = _clear_key(st, old_key)
+            st = st.set(S2K, victim, NIL)
+            return st, (victim, old_key, _ops4(head=n_re, tail=1, scan=n_re))
 
-    def on_miss(st: FlatState):
-        def fresh(st: FlatState):
-            return st, st.regs[R_SIZE], NIL, _ops4()
+        st, (new_slot, old_key, ops) = st.cond(
+            st.get(REGS, R_SIZE) < cap, _fresh, evict)
+        st = _fill(st, key, new_slot, cap)
+        return st, (old_key, _ops_add(ops, _ops4(head=1)))
 
-        def evict(st: FlatState):
-            ts, bit, now, victim, n_re = _clock_scan_evict(
-                st.ts, st.bit, st.regs[R_NOW], _occupied(st), p[P_MAX_SCAN]
-            )
-            old_key = st.slot2key[victim]
-            k2s = _clear_key(st.key2slot, old_key)
-            s2k = st.slot2key.at[victim].set(NIL)
-            regs = st.regs.at[R_NOW].set(now)
-            st = st._replace(key2slot=k2s, slot2key=s2k, ts=ts, bit=bit,
-                             regs=regs)
-            return st, victim, old_key, _ops4(head=n_re, tail=1, scan=n_re)
-
-        st, new_slot, old_key, ops = lax.cond(
-            st.regs[R_SIZE] < cap, fresh, evict, st
-        )
-        now = st.regs[R_NOW]
-        k2s = st.key2slot.at[key].set(new_slot)
-        s2k = st.slot2key.at[new_slot].set(key)
-        ts = st.ts.at[new_slot].set(now)
-        bit = st.bit.at[new_slot].set(0)
-        regs = st.regs.at[R_NOW].set(now + 1)
-        regs = regs.at[R_SIZE].set(jnp.minimum(st.regs[R_SIZE] + 1, cap))
-        st = st._replace(key2slot=k2s, slot2key=s2k, ts=ts, bit=bit,
-                         regs=regs)
-        return st, old_key, ops + _ops4(head=1)
-
-    st, evicted, ops = lax.cond(hit, on_hit, on_miss, st)
+    st, (evicted, ops) = st.cond(hit, lambda st: _set_bit(st, slot), on_miss)
     return st, hit, evicted, ops
 
 
@@ -352,85 +355,76 @@ def _clock_step(st: FlatState, key: jnp.ndarray, u: jnp.ndarray,
 # ---------------------------------------------------------------------------
 
 
-def _slru_step(st: FlatState, key: jnp.ndarray, u: jnp.ndarray,
-               p: jnp.ndarray, q: jnp.ndarray):
+def _slru_step(st: Any, key: Any, u: Any, p: Any, q: Any):
     del u, q
-    slot0 = st.key2slot[key]
+    slot0 = st.get(K2S, key)
     hit = slot0 != NIL
     slot = jnp.maximum(slot0, 0)
-    hit_T = hit & (st.aux[slot] != 0)
+    hit_T = hit & (st.get(AUX, slot) != 0)
     cap = p[P_CAP]
     prot_cap = p[P_PROT_CAP]
 
-    def on_hit_T(st: FlatState):
-        now = st.regs[R_NOW]
-        ts = st.ts.at[slot].set(now)
-        regs = st.regs.at[R_NOW].set(now + 1)
-        return (st._replace(ts=ts, regs=regs), NIL,
-                _ops4(delink=1, head=1))
+    def on_hit_T(st: Any):
+        now = st.get(REGS, R_NOW)
+        st = st.set(TS, slot, now)
+        st = st.set(REGS, R_NOW, now + 1)
+        return st, (NIL, _ops4(delink=1, head=1))
 
-    def on_hit_B(st: FlatState):
-        now = st.regs[R_NOW]
-        size_t = st.regs[R_SIZET]
-        aux = st.aux.at[slot].set(1)
-        ts = st.ts.at[slot].set(now)
+    def on_hit_B(st: Any):
+        now = st.get(REGS, R_NOW)
+        size_t = st.get(REGS, R_SIZET) + 1
+        st = st.set(AUX, slot, 1)
+        st = st.set(TS, slot, now)
         now = now + 1
-        size_t = size_t + 1
         # demote the protected tail back to B when T overflows; the slot
         # we just promoted carries the newest ts, so it is never the tail
         # (size_t > prot_cap >= 1 implies at least one older T member).
         demote = size_t > prot_cap
-        t_tail = _min_slot(ts, _occupied(st) & (aux != 0))
-        aux = aux.at[t_tail].set(jnp.where(demote, 0, aux[t_tail]))
-        ts = ts.at[t_tail].set(jnp.where(demote, now, ts[t_tail]))
-        now = now + demote.astype(jnp.int32)
-        size_t = size_t - demote.astype(jnp.int32)
-        regs = st.regs.at[R_NOW].set(now).at[R_SIZET].set(size_t)
-        ops = _ops4(delink=1, head=1 + demote.astype(jnp.int32),
-                    tail=demote)
-        return st._replace(ts=ts, aux=aux, regs=regs), NIL, ops
 
-    def on_miss(st: FlatState):
-        def fresh(st: FlatState):
-            return st, st.regs[R_SIZE], NIL, _ops4()
+        def do_demote(st: Any):
+            t_tail, _ = _min_slot(st, _in_aux)
+            st = st.set(AUX, t_tail, 0)
+            return st.set(TS, t_tail, now), ()
 
-        def evict(st: FlatState):
-            occ = _occupied(st)
-            b_mask = occ & (st.aux == 0)
+        st, _ = st.cond(demote, do_demote, _keep)
+        st = st.set(REGS, R_NOW, now + i32(demote))
+        st = st.set(REGS, R_SIZET, size_t - i32(demote))
+        ops = _ops4(delink=1, head=1 + i32(demote), tail=demote)
+        return st, (NIL, ops)
+
+    def on_miss(st: Any):
+        def evict(st: Any):
             # dlist order: evict B's tail, falling back to T's tail only
             # when B is empty.
-            victim = jnp.where(
-                jnp.any(b_mask),
-                _min_slot(st.ts, b_mask),
-                _min_slot(st.ts, occ & (st.aux != 0)),
-            )
-            old_key = st.slot2key[victim]
-            k2s = _clear_key(st.key2slot, old_key)
-            s2k = st.slot2key.at[victim].set(NIL)
-            st = st._replace(key2slot=k2s, slot2key=s2k)
-            return st, victim, old_key, _ops4(tail=1)
+            b_tail, b_ts = _min_slot(st, _not_aux)
+            st, victim = st.cond(
+                b_ts != INT32_MAX, lambda st: (st, b_tail),
+                lambda st: (st, _min_slot(st, _in_aux)[0]))
+            old_key = st.get(S2K, victim)
+            st = _clear_key(st, old_key)
+            st = st.set(S2K, victim, NIL)
+            return st, (victim, old_key, _ops4(tail=1))
 
-        st, new_slot, old_key, ops = lax.cond(
-            st.regs[R_SIZE] < cap, fresh, evict, st
-        )
-        now = st.regs[R_NOW]
+        st, (new_slot, old_key, ops) = st.cond(
+            st.get(REGS, R_SIZE) < cap, _fresh, evict)
+        now = st.get(REGS, R_NOW)
+        size = st.get(REGS, R_SIZE)
         # the victim may have come from T (B empty): shrink sizeT using
         # the *pre-clear* membership bit, then mark the slot probationary.
-        size_t = st.regs[R_SIZET] - (st.aux[new_slot] != 0).astype(jnp.int32)
-        k2s = st.key2slot.at[key].set(new_slot)
-        s2k = st.slot2key.at[new_slot].set(key)
-        ts = st.ts.at[new_slot].set(now)
-        aux = st.aux.at[new_slot].set(0)
-        regs = st.regs.at[R_NOW].set(now + 1).at[R_SIZET].set(size_t)
-        regs = regs.at[R_SIZE].set(jnp.minimum(st.regs[R_SIZE] + 1, cap))
-        st = st._replace(key2slot=k2s, slot2key=s2k, ts=ts, aux=aux,
-                         regs=regs)
-        return st, old_key, ops + _ops4(head=1)
+        size_t = st.get(REGS, R_SIZET) - i32(st.get(AUX, new_slot) != 0)
+        st = st.set(K2S, key, new_slot)
+        st = st.set(S2K, new_slot, key)
+        st = st.set(TS, new_slot, now)
+        st = st.set(AUX, new_slot, 0)
+        st = st.set(REGS, R_NOW, now + 1)
+        st = st.set(REGS, R_SIZET, size_t)
+        st = st.set(REGS, R_SIZE, jnp.minimum(size + 1, cap))
+        return st, (old_key, _ops_add(ops, _ops4(head=1)))
 
-    def on_hit_any(st: FlatState):
-        return lax.cond(hit_T, on_hit_T, on_hit_B, st)
+    def on_hit_any(st: Any):
+        return st.cond(hit_T, on_hit_T, on_hit_B)
 
-    st, evicted, ops = lax.cond(hit, on_hit_any, on_miss, st)
+    st, (evicted, ops) = st.cond(hit, on_hit_any, on_miss)
     return st, hit, evicted, ops
 
 
@@ -439,117 +433,87 @@ def _slru_step(st: FlatState, key: jnp.ndarray, u: jnp.ndarray,
 # ---------------------------------------------------------------------------
 
 
-def _s3_evict_m(st: FlatState, p: jnp.ndarray):
+def _s3_evict_m(st: Any, p: Any):
     """Evict from M with the CLOCK scan; returns (st, old_key, ops)."""
-    m_mask = _occupied(st) & (st.aux != 0)
-    ts, bit, now, victim, n_re = _clock_scan_evict(
-        st.ts, st.bit, st.regs[R_NOW], m_mask, p[P_MAX_SCAN]
-    )
-    old_key = st.slot2key[victim]
-    k2s = _clear_key(st.key2slot, old_key)
-    s2k = st.slot2key.at[victim].set(NIL)
-    aux = st.aux.at[victim].set(0)
-    regs = st.regs.at[R_NOW].set(now)
-    regs = regs.at[R_SIZEM].set(st.regs[R_SIZEM] - 1)
-    st = st._replace(key2slot=k2s, slot2key=s2k, ts=ts, bit=bit, aux=aux,
-                     regs=regs)
+    st, victim, n_re = _clock_scan_evict(st, _in_aux, p[P_MAX_SCAN])
+    old_key = st.get(S2K, victim)
+    st = _clear_key(st, old_key)
+    st = st.set(S2K, victim, NIL)
+    st = st.set(AUX, victim, 0)
+    st = st.set(REGS, R_SIZEM, st.get(REGS, R_SIZEM) - 1)
     return st, old_key, _ops4(head=n_re, tail=1, scan=n_re)
 
 
-def _s3fifo_step(st: FlatState, key: jnp.ndarray, u: jnp.ndarray,
-                 p: jnp.ndarray, q: jnp.ndarray):
+def _s3fifo_step(st: Any, key: Any, u: Any, p: Any, q: Any):
     del u, q
-    slot = st.key2slot[key]
+    slot = st.get(K2S, key)
     hit = slot != NIL
     cap = p[P_CAP]
 
-    def on_hit(st: FlatState):
-        bit = st.bit.at[jnp.maximum(slot, 0)].set(1)
-        return st._replace(bit=bit), NIL, _ops4()
+    def mk_room_m(st: Any, ops: Ops, evicted: Any):
+        st, old_key, eops = _s3_evict_m(st, p)
+        return st, (_ops_add(ops, eops), old_key)
 
-    def on_miss(st: FlatState):
-        in_ghost = jnp.any(st.ghost == key)
-        evicted = NIL
-        ops = _ops4()
+    def on_miss(st: Any):
+        _, ghost_at = st.argmin(lambda v: (v.ghost == key, v.slot))
+        in_ghost = ghost_at != INT32_MAX
 
-        def mk_room_m(args):
-            st, ops, evicted = args
-            st, old_key, eops = _s3_evict_m(st, p)
-            return st, ops + eops, old_key
+        need_m = in_ghost & (st.get(REGS, R_SIZEM) >= p[P_M_CAP])
+        st, (ops, evicted) = st.cond(need_m, mk_room_m, _keep, _ops4(), NIL)
 
-        need_m = in_ghost & (st.regs[R_SIZEM] >= p[P_M_CAP])
-        st, ops, evicted = lax.cond(
-            need_m, mk_room_m, lambda a: a, (st, ops, evicted)
-        )
+        def mk_room_s(st: Any, ops: Ops, evicted: Any):
+            s_tail, _ = _min_slot(st, _not_aux)
+            promote = st.get(BIT, s_tail) != 0
 
-        def mk_room_s(args):
-            st, ops, evicted = args
-            s_mask = _occupied(st) & (st.aux == 0)
-            s_tail = _min_slot(st.ts, s_mask)
-            promote = st.bit[s_tail] != 0
+            def do_promote(st: Any, ops: Ops, evicted: Any):
+                st, (ops, evicted) = st.cond(
+                    st.get(REGS, R_SIZEM) >= p[P_M_CAP], mk_room_m, _keep,
+                    ops, evicted)
+                now = st.get(REGS, R_NOW)
+                st = st.set(TS, s_tail, now)
+                st = st.set(AUX, s_tail, 1)
+                st = st.set(BIT, s_tail, 0)
+                st = st.set(REGS, R_NOW, now + 1)
+                st = st.set(REGS, R_SIZES, st.get(REGS, R_SIZES) - 1)
+                st = st.set(REGS, R_SIZEM, st.get(REGS, R_SIZEM) + 1)
+                return st, (_ops_add(ops, _ops4(head=1, tail=1)), evicted)
 
-            def do_promote(args):
-                st, ops, evicted = args
-                st, ops, evicted = lax.cond(
-                    st.regs[R_SIZEM] >= p[P_M_CAP], mk_room_m,
-                    lambda a: a, (st, ops, evicted)
-                )
-                now = st.regs[R_NOW]
-                ts = st.ts.at[s_tail].set(now)
-                aux = st.aux.at[s_tail].set(1)
-                bit = st.bit.at[s_tail].set(0)
-                regs = st.regs.at[R_NOW].set(now + 1)
-                regs = regs.at[R_SIZES].set(st.regs[R_SIZES] - 1)
-                regs = regs.at[R_SIZEM].set(st.regs[R_SIZEM] + 1)
-                st = st._replace(ts=ts, aux=aux, bit=bit, regs=regs)
-                return st, ops + _ops4(head=1, tail=1), evicted
+            def do_evict(st: Any, ops: Ops, evicted: Any):
+                old_key = st.get(S2K, s_tail)
+                st = _clear_key(st, old_key)
+                st = st.set(S2K, s_tail, NIL)
+                gpos = st.get(REGS, R_GPOS)
+                st = st.set(GHOST, gpos, old_key)
+                st = st.set(REGS, R_GPOS, (gpos + 1) % p[P_GHOST_CAP])
+                st = st.set(REGS, R_SIZES, st.get(REGS, R_SIZES) - 1)
+                return st, (_ops_add(ops, _ops4(tail=1)), old_key)
 
-            def do_evict(args):
-                st, ops, evicted = args
-                old_key = st.slot2key[s_tail]
-                k2s = _clear_key(st.key2slot, old_key)
-                s2k = st.slot2key.at[s_tail].set(NIL)
-                gpos = st.regs[R_GPOS]
-                ghost = st.ghost.at[gpos].set(old_key)
-                regs = st.regs.at[R_GPOS].set((gpos + 1) % p[P_GHOST_CAP])
-                regs = regs.at[R_SIZES].set(st.regs[R_SIZES] - 1)
-                st = st._replace(key2slot=k2s, slot2key=s2k, ghost=ghost,
-                                 regs=regs)
-                return st, ops + _ops4(tail=1), old_key
+            return st.cond(promote, do_promote, do_evict, ops, evicted)
 
-            return lax.cond(promote, do_promote, do_evict,
-                            (st, ops, evicted))
-
-        need_s = (~in_ghost) & (st.regs[R_SIZES] >= p[P_S_CAP])
-        st, ops, evicted = lax.cond(
-            need_s, mk_room_s, lambda a: a, (st, ops, evicted)
-        )
+        need_s = (~in_ghost) & (st.get(REGS, R_SIZES) >= p[P_S_CAP])
+        st, (ops, evicted) = st.cond(need_s, mk_room_s, _keep, ops, evicted)
 
         # place: next warmup slot while filling, else first freed slot
         # (room-making above guarantees one exists).
-        new_slot = jnp.where(
-            st.regs[R_SIZE] < cap,
-            st.regs[R_SIZE],
-            jnp.argmax(st.slot2key == NIL).astype(jnp.int32),
-        )
-        now = st.regs[R_NOW]
+        size = st.get(REGS, R_SIZE)
+        st, new_slot = st.cond(
+            size < cap, lambda st: (st, size),
+            lambda st: (st, st.argmin(lambda v: (v.slot2key == NIL,
+                                                 v.slot))[0]))
+        now = st.get(REGS, R_NOW)
         to_m = in_ghost
-        k2s = st.key2slot.at[key].set(new_slot)
-        s2k = st.slot2key.at[new_slot].set(key)
-        ts = st.ts.at[new_slot].set(now)
-        aux = st.aux.at[new_slot].set(to_m.astype(jnp.int32))
-        bit = st.bit.at[new_slot].set(0)
-        regs = st.regs.at[R_NOW].set(now + 1)
-        regs = regs.at[R_SIZES].set(
-            st.regs[R_SIZES] + (~to_m).astype(jnp.int32)
-        )
-        regs = regs.at[R_SIZEM].set(st.regs[R_SIZEM] + to_m.astype(jnp.int32))
-        regs = regs.at[R_SIZE].set(jnp.minimum(st.regs[R_SIZE] + 1, cap))
-        st = st._replace(key2slot=k2s, slot2key=s2k, ts=ts, aux=aux,
-                         bit=bit, regs=regs)
-        return st, evicted, ops + _ops4(head=1)
+        st = st.set(K2S, key, new_slot)
+        st = st.set(S2K, new_slot, key)
+        st = st.set(TS, new_slot, now)
+        st = st.set(AUX, new_slot, i32(to_m))
+        st = st.set(BIT, new_slot, 0)
+        st = st.set(REGS, R_NOW, now + 1)
+        st = st.set(REGS, R_SIZES, st.get(REGS, R_SIZES) + i32(~to_m))
+        st = st.set(REGS, R_SIZEM, st.get(REGS, R_SIZEM) + i32(to_m))
+        st = st.set(REGS, R_SIZE, jnp.minimum(size + 1, cap))
+        return st, (evicted, _ops_add(ops, _ops4(head=1)))
 
-    st, evicted, ops = lax.cond(hit, on_hit, on_miss, st)
+    st, (evicted, ops) = st.cond(hit, lambda st: _set_bit(st, slot), on_miss)
     return st, hit, evicted, ops
 
 
@@ -558,25 +522,16 @@ def _s3fifo_step(st: FlatState, key: jnp.ndarray, u: jnp.ndarray,
 # ---------------------------------------------------------------------------
 
 
-def _sieve_step(st: FlatState, key: jnp.ndarray, u: jnp.ndarray,
-                p: jnp.ndarray, q: jnp.ndarray):
+def _sieve_step(st: Any, key: Any, u: Any, p: Any, q: Any):
     del u, q
-    slot = st.key2slot[key]
+    slot = st.get(K2S, key)
     hit = slot != NIL
     cap = p[P_CAP]
 
-    def on_hit(st: FlatState):
-        bit = st.bit.at[jnp.maximum(slot, 0)].set(1)
-        return st._replace(bit=bit), NIL, _ops4()
-
-    def on_miss(st: FlatState):
-        def fresh(st: FlatState):
-            return st, st.regs[R_SIZE], NIL, _ops4()
-
-        def evict(st: FlatState):
-            occ = _occupied(st)
-            tail = _min_slot(st.ts, occ)
-            hand = st.regs[R_HAND]
+    def on_miss(st: Any):
+        def evict(st: Any):
+            tail, _ = _min_slot(st, _occ)
+            hand = st.get(REGS, R_HAND)
             start = jnp.where(hand == NIL, tail, hand)
 
             # The hand walk visits occupied slots in cyclic ts order from
@@ -588,49 +543,42 @@ def _sieve_step(st: FlatState, key: jnp.ndarray, u: jnp.ndarray,
             # ts >= ts[start] first, then the wrapped lower segment), or
             # ``start`` itself after a full clearing cycle; the cleared
             # slots are exactly the cyclic prefix strictly before it.
-            ts_start = st.ts[start]
-            bit0 = occ & (st.bit == 0)
+            ts_start = st.get(TS, start)
+
             # Cyclic order collapses to one argmin by biasing the wrapped
             # lower segment (ts < ts[start]) above the upper one; ts stays
             # far below the bias (one bump per push), so no overflow.
-            ck = st.ts + jnp.where(st.ts < ts_start, _WRAP_BIAS, 0)
-            idx = jnp.argmin(jnp.where(bit0, ck, _INT32_MAX))
-            found = bit0[idx]  # gather beats an any() reduction
+            def cyclic(ts: Any) -> Any:
+                return ts + jnp.where(ts < ts_start, _WRAP_BIAS, 0)
+
+            idx, ck_min = st.argmin(
+                lambda v: (_occ(v) & (v.bit == 0), cyclic(v.ts)))
+            found = ck_min != INT32_MAX
             victim = jnp.where(found, idx, start)
-            ts_v = st.ts[victim]
+            ts_v = st.get(TS, victim)
+            ck_v = cyclic(ts_v)
             # Cleared set = cyclic prefix strictly before the victim; a
             # full clearing cycle (no clear bit anywhere) clears the lot.
-            scanned = occ & jnp.where(found, ck < ck[victim], True)
-            bit = jnp.where(scanned, 0, st.bit)
-            scans = jnp.sum(scanned.astype(jnp.int32))
+            st, scans = st.update(
+                BIT, lambda v: (_occ(v) & (~found | (cyclic(v.ts) < ck_v)),
+                                0))
             # hand moves one step past the victim (NIL at the head ->
             # restart from the tail next eviction), computed *before* the
             # victim leaves the list, exactly like dl.prv[victim].
-            above = occ & (st.ts > ts_v)
-            nh = jnp.argmin(jnp.where(above, st.ts, _INT32_MAX))
-            new_hand = jnp.where(above[nh], nh, NIL)
-            old_key = st.slot2key[victim]
-            k2s = _clear_key(st.key2slot, old_key)
-            s2k = st.slot2key.at[victim].set(NIL)
-            regs = st.regs.at[R_HAND].set(new_hand)
-            st = st._replace(key2slot=k2s, slot2key=s2k, bit=bit, regs=regs)
-            return st, victim, old_key, _ops4(tail=1, scan=scans)
+            nh, ts_nh = _min_slot(st, lambda v: _occ(v) & (v.ts > ts_v))
+            new_hand = jnp.where(ts_nh != INT32_MAX, nh, NIL)
+            old_key = st.get(S2K, victim)
+            st = _clear_key(st, old_key)
+            st = st.set(S2K, victim, NIL)
+            st = st.set(REGS, R_HAND, new_hand)
+            return st, (victim, old_key, _ops4(tail=1, scan=scans))
 
-        st, new_slot, old_key, ops = lax.cond(
-            st.regs[R_SIZE] < cap, fresh, evict, st
-        )
-        now = st.regs[R_NOW]
-        k2s = st.key2slot.at[key].set(new_slot)
-        s2k = st.slot2key.at[new_slot].set(key)
-        ts = st.ts.at[new_slot].set(now)
-        bit = st.bit.at[new_slot].set(0)
-        regs = st.regs.at[R_NOW].set(now + 1)
-        regs = regs.at[R_SIZE].set(jnp.minimum(st.regs[R_SIZE] + 1, cap))
-        st = st._replace(key2slot=k2s, slot2key=s2k, ts=ts, bit=bit,
-                         regs=regs)
-        return st, old_key, ops + _ops4(head=1)
+        st, (new_slot, old_key, ops) = st.cond(
+            st.get(REGS, R_SIZE) < cap, _fresh, evict)
+        st = _fill(st, key, new_slot, cap)
+        return st, (old_key, _ops_add(ops, _ops4(head=1)))
 
-    st, evicted, ops = lax.cond(hit, on_hit, on_miss, st)
+    st, (evicted, ops) = st.cond(hit, lambda st: _set_bit(st, slot), on_miss)
     return st, hit, evicted, ops
 
 

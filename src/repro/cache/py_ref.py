@@ -24,44 +24,63 @@ class Access:
     ops: tuple  # (delink, head, tail, scan)
 
 
-class _KeyList(list):
-    """Key list with an O(1) membership set kept in sync.
+class _KeyList:
+    """Key list ordered head .. tail, as a doubly-linked list over dicts.
 
-    Cache lists hold each key at most once, and are only mutated through
-    ``insert`` / ``pop`` / ``remove`` — exactly the operations shadowed here.
-    Rebuilding ``set(self)`` per membership probe (the old ``_ListCache``
-    behaviour) made every access O(n) with a hidden allocation, which times
-    out the hypothesis differential tests and the host-side serving
-    controller at realistic capacities.
+    Cache lists hold each key at most once and change only at the head
+    (``push``), at the tail (``pop``) or at one named key (``remove``), so
+    every operation — membership and SIEVE's step toward the head included
+    — is O(1), whatever the capacity: a whole-trace replay at 2^19 slots
+    takes seconds.
     """
 
-    def __init__(self, iterable=()):
-        super().__init__(iterable)
-        self._set = set(self)
+    def __init__(self):
+        self._up: dict = {}    # key -> neighbour toward the head (None: head)
+        self._down: dict = {}  # key -> neighbour toward the tail (None: tail)
+        self.head: Optional[int] = None
+        self.tail: Optional[int] = None
 
-    def insert(self, index, key):
-        super().insert(index, key)
-        self._set.add(key)
-
-    def append(self, key):
-        super().append(key)
-        self._set.add(key)
-
-    def pop(self, index=-1):
-        key = super().pop(index)
-        self._set.discard(key)
-        return key
-
-    def remove(self, key):
-        super().remove(key)
-        self._set.discard(key)
+    def __len__(self):
+        return len(self._up)
 
     def __contains__(self, key):
-        return key in self._set
+        return key in self._up
+
+    def push(self, key):
+        """Insert ``key`` at the head."""
+        self._up[key] = None
+        self._down[key] = self.head
+        if self.head is None:
+            self.tail = key
+        else:
+            self._up[self.head] = key
+        self.head = key
+
+    def remove(self, key):
+        up = self._up.pop(key)
+        down = self._down.pop(key)
+        if up is None:
+            self.head = down
+        else:
+            self._down[up] = down
+        if down is None:
+            self.tail = up
+        else:
+            self._up[down] = up
+
+    def pop(self):
+        """Remove and return the tail key."""
+        key = self.tail
+        self.remove(key)
+        return key
+
+    def toward_head(self, key):
+        """The neighbour of ``key`` toward the head, None at the head."""
+        return self._up[key]
 
 
 class _ListCache:
-    """Shared machinery: key list ordered head(0) .. tail(-1)."""
+    """Shared machinery: one key list, ordered head .. tail."""
 
     def __init__(self, capacity: int):
         self.capacity = capacity
@@ -78,14 +97,14 @@ class LRU(_ListCache):
     def access(self, key: int, u: float = 0.0) -> Access:
         if key in self.order:
             self.order.remove(key)  # delink
-            self.order.insert(0, key)  # head update
+            self.order.push(key)  # head update
             return Access(True, -1, (1, 1, 0, 0))
         evicted = -1
         tail = 0
         if len(self.order) >= self.capacity:
             evicted = self.order.pop()  # tail update
             tail = 1
-        self.order.insert(0, key)  # head update
+        self.order.push(key)  # head update
         return Access(False, evicted, (0, 1, tail, 0))
 
 
@@ -101,7 +120,7 @@ class FIFO(_ListCache):
         if len(self.order) >= self.capacity:
             evicted = self.order.pop()
             tail = 1
-        self.order.insert(0, key)
+        self.order.push(key)
         return Access(False, evicted, (0, 1, tail, 0))
 
 
@@ -122,7 +141,7 @@ class ProbLRU(_ListCache):
         if key in self.order:
             if u >= self.q:  # promote with prob 1-q
                 self.order.remove(key)
-                self.order.insert(0, key)
+                self.order.push(key)
                 return Access(True, -1, (1, 1, 0, 0))
             return Access(True, -1, (0, 0, 0, 0))
         evicted = -1
@@ -130,7 +149,7 @@ class ProbLRU(_ListCache):
         if len(self.order) >= self.capacity:
             evicted = self.order.pop()
             tail = 1
-        self.order.insert(0, key)
+        self.order.push(key)
         return Access(False, evicted, (0, 1, tail, 0))
 
 
@@ -147,10 +166,10 @@ class Clock(_ListCache):
         scans = 0
         heads = 0
         while True:
-            s = self.order[-1]
+            s = self.order.tail
             if self.bit.get(s, False) and scans < self.max_scan:
                 self.order.pop()
-                self.order.insert(0, s)  # reinsert (head update)
+                self.order.push(s)  # reinsert (head update)
                 self.bit[s] = False
                 scans += 1
                 heads += 1
@@ -167,7 +186,7 @@ class Clock(_ListCache):
         ops = (0, 0, 0, 0)
         if len(self.order) >= self.capacity:
             evicted, ops = self._evict()
-        self.order.insert(0, key)
+        self.order.push(key)
         self.bit[key] = False
         ops = (ops[0], ops[1] + 1, ops[2], ops[3])
         return Access(False, evicted, ops)
@@ -186,15 +205,15 @@ class SLRU:
     def access(self, key: int, u: float = 0.0) -> Access:
         if key in self.T:
             self.T.remove(key)
-            self.T.insert(0, key)
+            self.T.push(key)
             return Access(True, -1, (1, 1, 0, 0))
         if key in self.B:
             self.B.remove(key)
-            self.T.insert(0, key)
+            self.T.push(key)
             d, h, t = 1, 1, 0
             if len(self.T) > self.protected_cap:
                 demoted = self.T.pop()
-                self.B.insert(0, demoted)
+                self.B.push(demoted)
                 t += 1
                 h += 1
             return Access(True, -1, (d, h, t, 0))
@@ -206,7 +225,7 @@ class SLRU:
             else:
                 evicted = self.T.pop()
             tail = 1
-        self.B.insert(0, key)
+        self.B.push(key)
         return Access(False, evicted, (0, 1, tail, 0))
 
 
@@ -238,10 +257,10 @@ class S3FIFO:
         scans = 0
         heads = 0
         while True:
-            s = self.M[-1]
+            s = self.M.tail
             if self.bit.get(s, False) and scans < 3:
                 self.M.pop()
-                self.M.insert(0, s)
+                self.M.push(s)
                 self.bit[s] = False
                 scans += 1
                 heads += 1
@@ -264,13 +283,13 @@ class S3FIFO:
             ops = [a + b for a, b in zip(ops, eops)]
 
         if (not in_ghost) and len(self.S) >= self.s_cap:
-            s_tail = self.S[-1]
+            s_tail = self.S.tail
             if self.bit.get(s_tail, False):
                 if len(self.M) >= self.m_cap:
                     evicted, eops = self._evict_m()
                     ops = [a + b for a, b in zip(ops, eops)]
                 self.S.pop()
-                self.M.insert(0, s_tail)
+                self.M.push(s_tail)
                 self.bit[s_tail] = False
                 ops[1] += 1  # head (M)
                 ops[2] += 1  # tail (S)
@@ -287,9 +306,9 @@ class S3FIFO:
                 ops[2] += 1
 
         if in_ghost:
-            self.M.insert(0, key)
+            self.M.push(key)
         else:
-            self.S.insert(0, key)
+            self.S.push(key)
         self.bit[key] = False
         ops[1] += 1
         return Access(False, evicted, tuple(ops))
@@ -311,21 +330,20 @@ class Sieve(_ListCache):
         evicted = -1
         ops = [0, 0, 0, 0]
         if len(self.order) >= self.capacity:
-            h = self.hand if (self.hand is not None and self.hand in self.order) else self.order[-1]
+            h = self.hand if (self.hand is not None and self.hand in self.order) else self.order.tail
             scans = 0
             while self.bit.get(h, False):
                 self.bit[h] = False
-                i = self.order.index(h)
-                h = self.order[i - 1] if i > 0 else self.order[-1]
+                up = self.order.toward_head(h)
+                h = self.order.tail if up is None else up
                 scans += 1
-            i = self.order.index(h)
-            self.hand = self.order[i - 1] if i > 0 else None
+            self.hand = self.order.toward_head(h)
             self.order.remove(h)
             self.bit.pop(h, None)
             evicted = h
             ops[2] += 1
             ops[3] += scans
-        self.order.insert(0, key)
+        self.order.push(key)
         self.bit[key] = False
         ops[1] += 1
         return Access(False, evicted, tuple(ops))

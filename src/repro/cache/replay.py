@@ -257,9 +257,9 @@ def _classify_lane(keys: jax.Array, hits: jax.Array, windows: jax.Array,
         cls = jnp.where(outstanding, DELAYED_HIT,
                         jnp.where(h, TRUE_HIT, TRUE_MISS))
         starts_fetch = (~outstanding) & (~h)
-        expiry = jnp.where(
-            starts_fetch, expiry.at[k].set(t + w), expiry
-        )
+        # write one selected scalar, not a select between whole tables:
+        # under vmap the latter copies every lane's (K,) table per request
+        expiry = expiry.at[k].set(jnp.where(starts_fetch, t + w, expiry[k]))
         return expiry, cls.astype(jnp.int8)
 
     exp0 = jnp.full_like(key_space_arr, _FAR_PAST)

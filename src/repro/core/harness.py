@@ -240,6 +240,9 @@ def run_cache_trace(policy: str, capacity: int, trace: np.ndarray, seed: int = 0
     return hits, ops
 
 
+_SCAN_MASK = (1 << 26) - 1
+
+
 def empirical_network(
     policy: str,
     hits: np.ndarray,
@@ -258,21 +261,24 @@ def empirical_network(
     w = int(len(hits) * warmup_frac)
     hits_m, ops_m = hits[w:], ops[w:]
     # vectorized profile histogram: each (hit, op-vector) row packs into one
-    # int64 (12 bits per op count), so the unique+count is a scalar sort —
-    # a per-request Python Counter (and even np.unique over rows, which
-    # sorts void views) dominated sweep time at 60k requests.
+    # int64 (12 bits per list-op count, 26 for the scan count — SIEVE's
+    # hand can clear thousands of bits in one eviction), so the
+    # unique+count is a scalar sort — a per-request Python Counter (and
+    # even np.unique over rows, which sorts void views) dominated sweep
+    # time at 60k requests.
     ops64 = np.asarray(ops_m, np.int64)
-    if ops64.size and ops64.max() > 0xFFF:
-        raise ValueError("op count exceeds 12-bit profile packing")
+    if ops64.size and (ops64[:, :3].max() > 0xFFF
+                       or ops64[:, 3].max() > _SCAN_MASK):
+        raise ValueError("op count exceeds the profile packing")
     code = (
-        (np.asarray(hits_m, np.int64) << 48)
-        | (ops64[:, 0] << 36) | (ops64[:, 1] << 24)
-        | (ops64[:, 2] << 12) | ops64[:, 3]
+        (np.asarray(hits_m, np.int64) << 62)
+        | (ops64[:, 0] << 50) | (ops64[:, 1] << 38)
+        | (ops64[:, 2] << 26) | ops64[:, 3]
     )
     uniq, counts = np.unique(code, return_counts=True)
     profiles = {
-        (bool(c >> 48), (int((c >> 36) & 0xFFF), int((c >> 24) & 0xFFF),
-                         int((c >> 12) & 0xFFF), int(c & 0xFFF))): int(n)
+        (bool(c >> 62), (int((c >> 50) & 0xFFF), int((c >> 38) & 0xFFF),
+                         int((c >> 26) & 0xFFF), int(c & _SCAN_MASK))): int(n)
         for c, n in zip(uniq, counts)
     }
     total = int(counts.sum())

@@ -1,22 +1,15 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
-
-"""Version compatibility for the Pallas TPU kernels.
-
-jax renamed ``pltpu.TPUCompilerParams`` to ``pltpu.CompilerParams`` (and will
-eventually drop the old name).  The pinned toolchain (jax 0.4.37) only has
-``TPUCompilerParams``; newer releases only have ``CompilerParams``.  Resolve
-whichever exists once, here, so every kernel imports the same symbol.
+"""Pallas TPU kernels: the replay and event-simulation engines
+(:mod:`~repro.kernels.replay`, :mod:`~repro.kernels.event_sim`) on the
+indexed-state interface of :mod:`~repro.indexed_state`, plus the model
+kernels behind :mod:`~repro.kernels.ops`.
 """
 
-from jax.experimental.pallas import tpu as _pltpu
+from __future__ import annotations
 
-CompilerParams = getattr(
-    _pltpu, "CompilerParams", getattr(_pltpu, "TPUCompilerParams", None)
-)
-if CompilerParams is None:  # pragma: no cover - future-proofing only
-    raise ImportError(
-        "jax.experimental.pallas.tpu exposes neither CompilerParams nor "
-        "TPUCompilerParams; unsupported jax version"
-    )
+import jax
+
+
+def on_tpu() -> bool:
+    """Whether kernels compile for the chip (else they run interpreted or
+    through their twins): the one platform test every caller uses."""
+    return jax.default_backend() == "tpu"

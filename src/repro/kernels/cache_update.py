@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams
 
 INT_MAX = jnp.int32(2**31 - 1)
 
@@ -89,7 +88,7 @@ def lru_batch_update(timestamps, accessed, now, *, tile: int = 512,
             jax.ShapeDtypeStruct((n_tiles,), jnp.int32),
             jax.ShapeDtypeStruct((n_tiles,), jnp.int32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,

@@ -20,7 +20,11 @@ Because the RNG stream differs, agreement with ``simulate_network`` is
 *statistical* (same network, same mean/dispersion laws — pinned within a
 few percent by tests), while the pallas kernel and the vmapped twin share
 :func:`_sim_lane` verbatim and are therefore bit-identical, the same
-twin-pair structure as the replay kernel.
+twin-pair structure as the replay kernel: the lane is written once
+against the indexed-state interface of :mod:`repro.indexed_state`, run on
+an ``ArrayState`` by the twin and on a ``RefState`` by the kernel (one
+grid cell per lane; job tables in VMEM rows, spec tables, per-job
+scalars and busy counts in SMEM, transcendentals on the vector unit).
 
 ``interpret=None`` auto-selects: real kernel on TPU, jitted vmapped twin
 on CPU; ``interpret=True`` runs the kernel body under the pallas
@@ -31,19 +35,20 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import NamedTuple, Optional, Sequence
+from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.simspec import (BIG_SEQ, INF_NS, SimResult, compile_network,
                                 stack_specs)
-from repro.kernels import CompilerParams
-from repro.obs.trace import (CLS_HIT, CLS_MISS, TraceRings, TraceScratch,
-                             decode_trace_grid, init_trace, ring_write_one)
+from repro.kernels import on_tpu
+from repro.indexed_state import ArrayState
+from repro.kernels.state import LANES, RefState, vmem_rows
+from repro.obs.trace import CLS_HIT, CLS_MISS, TraceRings, decode_trace_grid
 
 _GOLDEN = np.uint32(0x9E3779B9)
 _MIX1 = np.uint32(0x21F0AAAD)
@@ -61,328 +66,377 @@ def _mix(x: jnp.ndarray) -> jnp.ndarray:
     return x
 
 
-class _SpecArrays(NamedTuple):
-    """One lane's compiled network (the array fields of SimSpec)."""
+# -- table names (the indexed state _sim_lane is written against) -----------
+# per-lane spec, read only (2-D spec arrays flattened row-major)
+IS_QUEUE = "is_queue"      # (K,) int32 0/1
+SVC_NS = "svc_ns"          # (K,) float32 mean service
+DIST_ID = "dist_id"        # (K,) int32
+DIST_PAR = "dist_params"   # (K*4,) float32
+BRANCH_CUM = "branch_cum"  # (B,) float32 cumulative branch law
+VISITS = "visits"          # (B*L,) int32 routes, -1 padded
+SERVERS = "servers"        # (K,) int32
+SPEC_TABLES = (IS_QUEUE, SVC_NS, DIST_ID, DIST_PAR, BRANCH_CUM, VISITS,
+               SERVERS)
+# per-job state: READY/STATION/ENQ form the vector group
+READY = "ready"            # (N,) int32 ns to the job's next event
+STATION = "station"        # (N,) int32
+ENQ = "enq"                # (N,) int32 FIFO enqueue sequence, BIG_SEQ idle
+JOB_GROUP = (READY, STATION, ENQ)
+BRANCH = "branch"          # (N,) int32
+POS = "pos"                # (N,) int32 position on the route
+BUSY = "busy"              # (K,) int32 busy servers per station
+# tracing (trace_cap > 0): per-branch miss class, the record rings
+# (cap + 1 rows, the last one scrap) and per-job visit stamps
+BMISS = "bmiss"            # (B,) int32
+RING_TABLES = ("req", "r_branch", "cls", "nvis", "parked_us")
+RING_ROWS = ("enter_us", "leave_us")        # ((cap+1)*L,) float32
+SCR_ENTER, SCR_LEAVE = "scr_enter_us", "scr_leave_us"  # (N*L,) float32
+# job-table values a padded kernel slot holds: never the next event,
+# never a waiter (a padded slot is invisible to every mask of _sim_lane)
+_JOB_PAD = {READY: INF_NS, STATION: np.int32(-1), ENQ: BIG_SEQ}
 
-    is_queue: jnp.ndarray    # (K,) bool
-    svc_ns: jnp.ndarray      # (K,) f32
-    dist_id: jnp.ndarray     # (K,) i32
-    dist_params: jnp.ndarray  # (K, 4) f32
-    branch_cum: jnp.ndarray  # (B,) f32
-    visits: jnp.ndarray      # (B, L) i32
-    servers: jnp.ndarray     # (K,) i32
 
-
-def _service_ns(u: jnp.ndarray, spec: _SpecArrays, k: jnp.ndarray):
+def _service_ns(st, u, k):
     """Service draw (ns, int32 >= 1) — the `_sample_service_ns` formulas
     with the uniform supplied by the caller's counter stream."""
-    mean = spec.svc_ns[k]
-    s_exp = -jnp.log(u)
-    alpha, lo, hi, raw_mean = (spec.dist_params[k, i] for i in range(4))
-    ratio = 1.0 - (lo / hi) ** alpha
-    s_par = lo * (1.0 - u * ratio) ** (-1.0 / alpha) / raw_mean
-    unit = jnp.select(
-        [spec.dist_id[k] == 0, spec.dist_id[k] == 1, spec.dist_id[k] == 2],
-        [jnp.float32(1.0), s_exp, s_par],
-    )
-    return jnp.maximum(jnp.round(unit * mean), 1.0).astype(jnp.int32)
+    params = [st.get(DIST_PAR, k * 4 + i) for i in range(4)]
+
+    def draw(u, mean, did, alpha, lo, hi, raw_mean):
+        s_exp = -jnp.log(u)
+        ratio = 1.0 - (lo / hi) ** alpha
+        s_par = lo * (1.0 - u * ratio) ** (-1.0 / alpha) / raw_mean
+        unit = jnp.where(did == 0, np.float32(1.0),
+                         jnp.where(did == 1, s_exp, s_par))
+        return jnp.maximum(jnp.round(unit * mean), np.float32(1.0))
+
+    ns = st.vmath(draw, u, st.get(SVC_NS, k),
+                  st.get(DIST_ID, k).astype(jnp.float32), *params)
+    return ns.astype(jnp.int32)
 
 
-def _sim_lane(spec: _SpecArrays, seed: jnp.ndarray, *, n_requests: int,
-              warmup: int, mpl: int, max_events: int, trace_cap: int = 0,
-              bmiss=None):
+def _sim_lane(st, seed, *, n_requests: int, warmup: int, mpl: int,
+              max_events: int, route_len: int, trace_cap: int = 0):
     """One (p_hit, seed) lane of the closed-loop simulation.
 
-    Shared verbatim by the pallas kernel body and the vmapped CPU twin.
-    Returns (x, completed, events, t_measured_us) — plus the filled
-    :class:`~repro.obs.trace.TraceRings` when ``trace_cap > 0``
-    (``bmiss`` is then the (B,) per-branch miss-class table; tracing
-    draws no RNG, so the simulated system is bit-identical either way).
+    Shared verbatim by the pallas kernel body (``st`` a RefState) and the
+    vmapped CPU twin (an ArrayState).  Returns (st, x, completed, events,
+    t_measured_us, n_traced); with ``trace_cap > 0`` the record rings in
+    ``st`` are filled (tracing draws no RNG, so the simulated system is
+    bit-identical either way).
     """
     n = mpl
+    n_l = route_len
     base = _mix(seed.astype(jnp.uint32) + _GOLDEN)
 
     def u01(ctr):
         z = _mix(base + jnp.asarray(ctr).astype(jnp.uint32) * _GOLDEN)
-        u = (z >> np.uint32(8)).astype(jnp.float32) * _INV24
-        return jnp.clip(u, 1e-7, 1.0 - 1e-7)
-
-    def pick_branch(u):
-        # searchsorted-left over the cumulative branch law
-        return jnp.sum(spec.branch_cum < u).astype(jnp.int32)
+        # 24 bits: exact through int32 on the way to float32
+        z24 = (z >> np.uint32(8)).astype(jnp.int32)
+        return st.vmath(lambda z: jnp.clip(z.astype(jnp.float32) * _INV24,
+                                           np.float32(1e-7),
+                                           np.float32(1.0 - 1e-7)), z24)
 
     # --- init: all mpl jobs start a request at their (think) first station.
-    idx = jnp.arange(n, dtype=jnp.int32)
-    branch0 = jnp.sum(
-        spec.branch_cum[None, :] < u01(idx)[:, None], axis=1
-    ).astype(jnp.int32)
-    station0 = spec.visits[branch0, 0]
-    svc0 = jax.vmap(lambda u, k: _service_ns(u, spec, k))(u01(n + idx),
-                                                          station0)
+    def init_job(st, j):
+        b = st.count_less(BRANCH_CUM, u01(j))
+        k0 = st.get(VISITS, b * n_l)
+        st = st.set(BRANCH, j, b)
+        st = st.set(POS, j, 0)
+        st = st.set(STATION, j, k0)
+        st = st.set(ENQ, j, BIG_SEQ)
+        return st.set(READY, j, _service_ns(st, u01(n + j), k0)), j + 1
 
-    carry = (
-        svc0,                                    # ready_ns (N,)
-        station0,                                # station (N,)
-        branch0,                                 # branch (N,)
-        jnp.zeros((n,), jnp.int32),              # pos (N,)
-        jnp.full((n,), BIG_SEQ),                 # enq_seq (N,)
-        jnp.zeros(spec.is_queue.shape, jnp.int32),  # busy_count (K,)
-        jnp.int32(0),                            # seq_ctr
-        jnp.int32(0),                            # completed
-        jnp.float32(0.0),                        # elapsed_us
-        jnp.int32(-1),                           # warm_completed
-        jnp.float32(0.0),                        # warm_elapsed_us
-        jnp.int32(2 * n),                        # rng counter
-        jnp.int32(0),                            # events
-    ) + init_trace(trace_cap, n, spec.visits.shape[1])
+    st, _ = st.while_loop(lambda st, j: j < n, init_job, np.int32(0))
 
-    def cond(carry):
-        completed, events = carry[7], carry[12]
+    def cond(st, carry):
+        completed, events = carry[1], carry[6]
         return (completed < n_requests) & (events < max_events)
 
-    def body(carry):
-        (ready_ns, station, branch, pos, enq_seq, busy_count, seq_ctr,
-         completed, elapsed_us, warm_completed, warm_elapsed_us, ctr,
-         events) = carry[:13]
-        if trace_cap:
-            rings, scr = carry[13], carry[14]
+    def body(st, carry):
+        (seq_ctr, completed, elapsed_us, warm_completed, warm_elapsed_us,
+         ctr, events, n_traced) = carry
         u_svc1 = u01(ctr)
         u_svc2 = u01(ctr + 1)
         u_branch = u01(ctr + 2)
         ctr = ctr + 3
 
-        j = jnp.argmin(ready_ns).astype(jnp.int32)
-        t = ready_ns[j]
-        finite = ready_ns < INF_NS
-        ready = jnp.where(finite, ready_ns - t, INF_NS)
-        elapsed_us = elapsed_us + t.astype(jnp.float32) * 1e-3
-        k_cur = station[j]
+        j, t = st.argmin(lambda v: (True, v.ready))
+        elapsed_us = elapsed_us + t.astype(jnp.float32) * np.float32(1e-3)
+        st, _ = st.update(READY, lambda v: (v.ready < INF_NS, v.ready - t))
+        k_cur = st.get(STATION, j)
 
         # ---- hand the server job j held (if any) to its FIFO successor.
-        def release(args):
-            ready, busy_count, enq_seq = args
-            waiting = (station == k_cur) & (ready == INF_NS)
-            waiting = waiting.at[j].set(False)
-            seqs = jnp.where(waiting, enq_seq, BIG_SEQ)
-            w = jnp.argmin(seqs).astype(jnp.int32)
-            has_waiter = seqs[w] < BIG_SEQ
-            svc = _service_ns(u_svc1, spec, k_cur)
-            ready = jnp.where(has_waiter, ready.at[w].set(svc), ready)
-            enq_seq = jnp.where(has_waiter, enq_seq.at[w].set(BIG_SEQ),
-                                enq_seq)
-            busy_count = busy_count.at[k_cur].add(
-                jnp.where(has_waiter, 0, -1).astype(jnp.int32)
-            )
-            return ready, busy_count, enq_seq
+        def release(st):
+            w, seq_w = st.argmin(lambda v: (
+                (v.station == k_cur) & (v.ready == INF_NS) & (v.slot != j),
+                v.enq))
+            has_waiter = seq_w < BIG_SEQ
+            svc = _service_ns(st, u_svc1, k_cur)
+            st = st.set_if(READY, w, has_waiter, svc)
+            st = st.set_if(ENQ, w, has_waiter, BIG_SEQ)
+            busy = st.get(BUSY, k_cur)
+            return st.set(BUSY, k_cur, busy - (~has_waiter).astype(jnp.int32)), ()
 
-        ready, busy_count, enq_seq = lax.cond(
-            spec.is_queue[k_cur], release, lambda a: a,
-            (ready, busy_count, enq_seq),
-        )
+        st, _ = st.cond(st.get(IS_QUEUE, k_cur) != 0, release,
+                        lambda st: (st, ()))
 
         # ---- advance job j along its route (or complete + restart).
-        nxt_pos = pos[j] + 1
-        route_len = spec.visits.shape[1]
+        pos_j = st.get(POS, j)
+        branch_j = st.get(BRANCH, j)
+        nxt_pos = pos_j + 1
         route_next = jnp.where(
-            nxt_pos < route_len,
-            spec.visits[branch[j], nxt_pos % route_len], -1,
-        )
+            nxt_pos < n_l, st.get(VISITS, branch_j * n_l + nxt_pos % n_l),
+            np.int32(-1))
         done = route_next < 0
-        new_branch = pick_branch(u_branch)
-        branch_j = jnp.where(done, new_branch, branch[j])
-        pos_j = jnp.where(done, 0, nxt_pos)
-        k_next = jnp.where(done, spec.visits[new_branch, 0], route_next)
+        new_branch = st.count_less(BRANCH_CUM, u_branch)
+        branch_next = jnp.where(done, new_branch, branch_j)
+        pos_next = jnp.where(done, np.int32(0), nxt_pos)
+        k_next = jnp.where(done, st.get(VISITS, new_branch * n_l), route_next)
         if trace_cap:
             # Stamp j's departure from its current visit; on completion
             # emit the finished request's record (req id = completed so
-            # far — the same id the threefry engine would assign).
-            leave_m = scr.leave_us.at[j, pos[j]].set(elapsed_us)
-            cls_j = jnp.where(bmiss[branch[j]], CLS_MISS,
-                              CLS_HIT).astype(jnp.int32)
-            rings = ring_write_one(rings, done, completed, branch[j], cls_j,
-                                   pos[j] + 1, jnp.float32(0.0),
-                                   scr.enter_us[j], leave_m[j])
-            scr = TraceScratch(
-                enter_us=scr.enter_us.at[j, pos_j].set(elapsed_us),
-                leave_us=leave_m,
-            )
+            # far — the same id the threefry engine would assign) into
+            # the ring, else into its scrap row.
+            st = st.set(SCR_LEAVE, j * n_l + pos_j, elapsed_us)
+            idx = jnp.where(done, completed % trace_cap, np.int32(trace_cap))
+            miss = st.get(BMISS, branch_j) != 0
+            rec = (completed, branch_j,
+                   jnp.where(miss, np.int32(CLS_MISS), np.int32(CLS_HIT)),
+                   pos_j + 1, np.float32(0.0))
+            for i, name in enumerate(RING_TABLES):
+                st = st.set(name, idx, rec[i])
+            for name, scr in zip(RING_ROWS, (SCR_ENTER, SCR_LEAVE)):
+                for l in range(n_l):
+                    st = st.set(name, idx * n_l + l,
+                                st.get(scr, j * n_l + l))
+            st = st.set(SCR_ENTER, j * n_l + pos_next, elapsed_us)
+            n_traced = n_traced + done.astype(jnp.int32)
         completed = completed + done.astype(jnp.int32)
 
         # ---- place j at k_next.
-        svc_next = _service_ns(u_svc2, spec, k_next)
-        is_q = spec.is_queue[k_next]
-        has_slot = busy_count[k_next] < spec.servers[k_next]
-        starts_now = (~is_q) | has_slot
+        svc_next = _service_ns(st, u_svc2, k_next)
+        is_q = st.get(IS_QUEUE, k_next) != 0
+        busy = st.get(BUSY, k_next)
+        starts_now = (~is_q) | (busy < st.get(SERVERS, k_next))
         waits = ~starts_now
-        ready = ready.at[j].set(jnp.where(starts_now, svc_next, INF_NS))
-        enq_seq = enq_seq.at[j].set(jnp.where(waits, seq_ctr, BIG_SEQ))
+        st = st.set(READY, j, jnp.where(starts_now, svc_next, INF_NS))
+        st = st.set(ENQ, j, jnp.where(waits, seq_ctr, BIG_SEQ))
         seq_ctr = seq_ctr + waits.astype(jnp.int32)
-        busy_count = busy_count.at[k_next].add(
-            (is_q & starts_now).astype(jnp.int32)
-        )
+        st = st.set(BUSY, k_next, busy + (is_q & starts_now).astype(jnp.int32))
+        st = st.set(STATION, j, k_next)
+        st = st.set(BRANCH, j, branch_next)
+        st = st.set(POS, j, pos_next)
 
         # ---- warmup bookkeeping.
         warm_now = (completed >= warmup) & (warm_completed < 0)
         warm_completed = jnp.where(warm_now, completed, warm_completed)
         warm_elapsed_us = jnp.where(warm_now, elapsed_us, warm_elapsed_us)
+        return st, (seq_ctr, completed, elapsed_us, warm_completed,
+                    warm_elapsed_us, ctr, events + 1, n_traced)
 
-        return (ready, station.at[j].set(k_next), branch.at[j].set(branch_j),
-                pos.at[j].set(pos_j), enq_seq, busy_count, seq_ctr,
-                completed, elapsed_us, warm_completed, warm_elapsed_us, ctr,
-                events + 1) + ((rings, scr) if trace_cap else ())
-
-    carry = lax.while_loop(cond, body, carry)
-    (_, _, _, _, _, _, _, completed, elapsed_us, warm_completed,
-     warm_elapsed_us, _, events) = carry[:13]
+    zero = np.int32(0)
+    st, carry = st.while_loop(cond, body, (
+        zero,                  # seq_ctr
+        zero,                  # completed
+        np.float32(0.0),       # elapsed_us
+        np.int32(-1),          # warm_completed
+        np.float32(0.0),       # warm_elapsed_us
+        np.int32(2 * n),       # rng counter
+        zero,                  # events
+        zero,                  # records emitted to the trace ring
+    ))
+    (_, completed, elapsed_us, warm_completed, warm_elapsed_us, _, events,
+     n_traced) = carry
     n_measured = completed - warm_completed
-    t_measured = jnp.maximum(elapsed_us - warm_elapsed_us, 1e-6)
+    t_measured = jnp.maximum(elapsed_us - warm_elapsed_us, np.float32(1e-6))
     x = n_measured.astype(jnp.float32) / t_measured
-    if trace_cap:
-        return x, completed, events, t_measured, carry[13]
-    return x, completed, events, t_measured
+    return st, x, completed, events, t_measured, n_traced
+
+
+def _trace_tables(trace_cap: int, n_jobs: int, route_len: int):
+    """Zero-state trace tables (name -> (length, dtype, fill))."""
+    rows = (trace_cap + 1) * route_len
+    return {
+        "req": (trace_cap + 1, jnp.int32, -1),
+        "r_branch": (trace_cap + 1, jnp.int32, 0),
+        "cls": (trace_cap + 1, jnp.int32, 0),
+        "nvis": (trace_cap + 1, jnp.int32, 0),
+        "parked_us": (trace_cap + 1, jnp.float32, 0.0),
+        "enter_us": (rows, jnp.float32, 0.0),
+        "leave_us": (rows, jnp.float32, 0.0),
+        SCR_ENTER: (n_jobs * route_len, jnp.float32, 0.0),
+        SCR_LEAVE: (n_jobs * route_len, jnp.float32, 0.0),
+    }
+
+
+def _rings(tabs, n_traced, trace_cap: int, route_len: int) -> TraceRings:
+    """TraceRings from (lanes, >= length) trace tables."""
+    c1 = trace_cap + 1
+
+    def flat(name, n):
+        a = tabs[name]
+        return a.reshape(a.shape[0], -1)[:, :n]
+
+    return TraceRings(
+        n_count=n_traced, req=flat("req", c1), branch=flat("r_branch", c1),
+        cls=flat("cls", c1), nvis=flat("nvis", c1),
+        parked_us=flat("parked_us", c1),
+        enter_us=flat("enter_us", c1 * route_len).reshape(-1, c1, route_len),
+        leave_us=flat("leave_us", c1 * route_len).reshape(-1, c1, route_len),
+    )
 
 
 @functools.partial(jax.jit,
                    static_argnames=("n_requests", "warmup", "mpl",
-                                    "max_events", "trace_cap"))
-def _twin_grid(spec_arrays, seeds, bmiss=None, *, n_requests: int,
-               warmup: int, mpl: int, max_events: int, trace_cap: int = 0):
+                                    "max_events", "route_len", "trace_cap"))
+def _twin_grid(spec_tabs, seeds, bmiss=None, *, n_requests: int,
+               warmup: int, mpl: int, max_events: int, route_len: int,
+               trace_cap: int = 0):
+    """The CPU twin: ``_sim_lane`` vmapped over lanes on ArrayState."""
+    n_k = spec_tabs[IS_QUEUE].shape[1]
+
+    def lane(spec, seed, bm):
+        tabs = {
+            **spec,
+            READY: jnp.zeros((mpl,), jnp.int32),
+            STATION: jnp.zeros((mpl,), jnp.int32),
+            ENQ: jnp.zeros((mpl,), jnp.int32),
+            BRANCH: jnp.zeros((mpl,), jnp.int32),
+            POS: jnp.zeros((mpl,), jnp.int32),
+            BUSY: jnp.zeros((n_k,), jnp.int32),
+        }
+        if trace_cap:
+            tabs[BMISS] = bm
+            for name, (n, dt, fill) in _trace_tables(
+                    trace_cap, mpl, route_len).items():
+                tabs[name] = jnp.full((n,), fill, dt)
+        st, *out = _sim_lane(
+            ArrayState(tabs, READY), seed, n_requests=n_requests,
+            warmup=warmup, mpl=mpl, max_events=max_events,
+            route_len=route_len, trace_cap=trace_cap)
+        return out, {k: st.tabs[k] for k in RING_TABLES + RING_ROWS
+                     if trace_cap}
+
+    in_bm = 0 if trace_cap else None
+    (x, completed, events, t_meas, n_traced), tr = jax.vmap(
+        lane, in_axes=(0, 0, in_bm))(spec_tabs, seeds, bmiss)
+    rings = _rings(tr, n_traced, trace_cap, route_len) if trace_cap else None
+    return x, completed, events, t_meas, rings
+
+
+def _sim_kernel(*refs, in_names, scratch_names, ring_names, n_requests: int,
+                warmup: int, mpl: int, max_events: int, route_len: int,
+                trace_cap: int):
+    """One grid cell = one (p_hit, seed) lane's full event loop.
+
+    Refs arrive as the named inputs, the four per-lane results (then,
+    when tracing, the record count and the ring tables) and the named
+    scratch tables.
+    """
+    n_in = len(in_names)
+    n_out = 4 + (1 + len(ring_names) if trace_cap else 0)
+    outs = refs[n_in:n_in + n_out]
+    tables = {**dict(zip(in_names, refs[:n_in])),
+              **dict(zip(scratch_names, refs[n_in + n_out:])),
+              **dict(zip(ring_names, outs[5:]))}
+    seed = tables.pop("seed")[0, 0]
+    for name, fill in _JOB_PAD.items():
+        tables[name][...] = jnp.full(tables[name].shape, fill, jnp.int32)
+    for k in range(tables[BUSY].shape[0]):
+        tables[BUSY][k] = np.int32(0)
+    vmem = JOB_GROUP
     if trace_cap:
-        def lane_tr(sp, seed, bm):
-            return _sim_lane(_SpecArrays(*sp), seed, n_requests=n_requests,
-                             warmup=warmup, mpl=mpl, max_events=max_events,
-                             trace_cap=trace_cap, bmiss=bm)
-
-        return jax.vmap(lane_tr, in_axes=(0, 0, 0))(spec_arrays, seeds,
-                                                    bmiss)
-
-    def lane(sp, seed):
-        return _sim_lane(_SpecArrays(*sp), seed, n_requests=n_requests,
-                         warmup=warmup, mpl=mpl, max_events=max_events)
-
-    return jax.vmap(lane, in_axes=(0, 0))(spec_arrays, seeds)
+        for name, (_, dt, fill) in _trace_tables(trace_cap, mpl,
+                                                 route_len).items():
+            tables[name][...] = jnp.full(tables[name].shape, fill, dt)
+            vmem += (name,)
+    st = RefState(tables, smem=[k for k in tables if k not in vmem],
+                  block_rows=tables[READY].shape[0], n_blocks=1)
+    _, x, completed, events, t_meas, n_traced = _sim_lane(
+        st, seed, n_requests=n_requests, warmup=warmup, mpl=mpl,
+        max_events=max_events, route_len=route_len, trace_cap=trace_cap)
+    for ref, v in zip(outs, (x, completed, events, t_meas, n_traced)):
+        ref[0, 0] = v
 
 
-def _sim_kernel(isq_ref, svc_ref, did_ref, dpar_ref, bcum_ref, visits_ref,
-                srv_ref, seed_ref, x_ref, comp_ref, ev_ref, tmeas_ref, *,
-                n_requests: int, warmup: int, mpl: int, max_events: int):
-    spec = _SpecArrays(
-        is_queue=isq_ref[0] != 0,
-        svc_ns=svc_ref[0],
-        dist_id=did_ref[0],
-        dist_params=dpar_ref[0],
-        branch_cum=bcum_ref[0],
-        visits=visits_ref[0],
-        servers=srv_ref[0],
-    )
-    x, completed, events, t_meas = _sim_lane(
-        spec, seed_ref[0], n_requests=n_requests, warmup=warmup, mpl=mpl,
-        max_events=max_events,
-    )
-    x_ref[0] = x
-    comp_ref[0] = completed
-    ev_ref[0] = events
-    tmeas_ref[0] = t_meas
+@functools.partial(jax.jit,
+                   static_argnames=("n_requests", "warmup", "mpl",
+                                    "max_events", "route_len", "trace_cap",
+                                    "interpret"))
+def pallas_grid(spec_tabs, seeds, bmiss=None, *, n_requests: int,
+                warmup: int, mpl: int, max_events: int, route_len: int,
+                trace_cap: int = 0, interpret: bool = False):
+    """The event-sim kernel over a lane grid: one dispatch.
 
-
-def _sim_kernel_traced(isq_ref, svc_ref, did_ref, dpar_ref, bcum_ref,
-                       visits_ref, srv_ref, seed_ref, bmiss_ref, x_ref,
-                       comp_ref, ev_ref, tmeas_ref, tn_ref, treq_ref,
-                       tbr_ref, tcls_ref, tnv_ref, tpk_ref, ten_ref,
-                       tlv_ref, *, n_requests: int, warmup: int, mpl: int,
-                       max_events: int, trace_cap: int):
-    """Traced variant of :func:`_sim_kernel` — the ring-buffer outputs ride
-    along as extra (shape-static, ``trace_cap + 1``-row) out refs."""
-    spec = _SpecArrays(
-        is_queue=isq_ref[0] != 0,
-        svc_ns=svc_ref[0],
-        dist_id=did_ref[0],
-        dist_params=dpar_ref[0],
-        branch_cum=bcum_ref[0],
-        visits=visits_ref[0],
-        servers=srv_ref[0],
-    )
-    x, completed, events, t_meas, rings = _sim_lane(
-        spec, seed_ref[0], n_requests=n_requests, warmup=warmup, mpl=mpl,
-        max_events=max_events, trace_cap=trace_cap,
-        bmiss=bmiss_ref[0] != 0,
-    )
-    x_ref[0] = x
-    comp_ref[0] = completed
-    ev_ref[0] = events
-    tmeas_ref[0] = t_meas
-    tn_ref[0] = rings.n_count
-    treq_ref[0] = rings.req
-    tbr_ref[0] = rings.branch
-    tcls_ref[0] = rings.cls
-    tnv_ref[0] = rings.nvis
-    tpk_ref[0] = rings.parked_us
-    ten_ref[0] = rings.enter_us
-    tlv_ref[0] = rings.leave_us
-
-
-def _pallas_grid(spec_arrays, seeds, bmiss=None, *, n_requests: int,
-                 warmup: int, mpl: int, max_events: int, interpret: bool,
-                 trace_cap: int = 0):
-    isq, svc, did, dpar, bcum, visits, srv = spec_arrays
+    ``spec_tabs`` maps the SPEC_TABLES names to (lanes, width) arrays,
+    ``seeds`` is (lanes,) int32.  Returns (x, completed, events,
+    t_measured_us, rings) per lane — rings None unless ``trace_cap``.
+    Jitted, so ``pallas_grid.lower(...)`` AOT-compiles the kernel alone.
+    """
     n_lanes = seeds.shape[0]
-    n_k = isq.shape[1]
-    n_b, n_l = visits.shape[1], visits.shape[2]
 
-    def row(*block):
-        return pl.BlockSpec(block, lambda i: (i,) + (0,) * (len(block) - 1))
+    def lane_block(width):
+        return pl.BlockSpec((None, 1, width), lambda i: (i, 0, 0),
+                            memory_space=pltpu.SMEM)
 
-    in_specs = [
-        row(1, n_k), row(1, n_k), row(1, n_k), row(1, n_k, 4),
-        row(1, n_b), row(1, n_b, n_l), row(1, n_k), row(1),
-    ]
-    out_specs = [row(1), row(1), row(1), row(1)]
-    out_shape = [
-        jax.ShapeDtypeStruct((n_lanes,), jnp.float32),
-        jax.ShapeDtypeStruct((n_lanes,), jnp.int32),
-        jax.ShapeDtypeStruct((n_lanes,), jnp.int32),
-        jax.ShapeDtypeStruct((n_lanes,), jnp.float32),
-    ]
-    operands = [isq.astype(jnp.int32), svc, did, dpar, bcum, visits, srv,
-                seeds]
+    def lane_rows(rows):
+        return pl.BlockSpec((None, rows, LANES), lambda i: (i, 0, 0))
+
+    in_names = list(SPEC_TABLES) + ["seed"]
+    operands = [spec_tabs[k][:, None, :] for k in SPEC_TABLES]
+    operands.append(seeds.astype(jnp.int32)[:, None, None])
     if trace_cap:
-        cap1 = trace_cap + 1
-        kernel = functools.partial(
-            _sim_kernel_traced, n_requests=n_requests, warmup=warmup,
-            mpl=mpl, max_events=max_events, trace_cap=trace_cap,
-        )
-        in_specs.append(row(1, n_b))
-        operands.append(bmiss.astype(jnp.int32))
-        out_specs += [row(1), row(1, cap1), row(1, cap1), row(1, cap1),
-                      row(1, cap1), row(1, cap1), row(1, cap1, n_l),
-                      row(1, cap1, n_l)]
-        out_shape += [
-            jax.ShapeDtypeStruct((n_lanes,), jnp.int32),
-            jax.ShapeDtypeStruct((n_lanes, cap1), jnp.int32),
-            jax.ShapeDtypeStruct((n_lanes, cap1), jnp.int32),
-            jax.ShapeDtypeStruct((n_lanes, cap1), jnp.int32),
-            jax.ShapeDtypeStruct((n_lanes, cap1), jnp.int32),
-            jax.ShapeDtypeStruct((n_lanes, cap1), jnp.float32),
-            jax.ShapeDtypeStruct((n_lanes, cap1, n_l), jnp.float32),
-            jax.ShapeDtypeStruct((n_lanes, cap1, n_l), jnp.float32),
-        ]
-    else:
-        kernel = functools.partial(_sim_kernel, n_requests=n_requests,
-                                   warmup=warmup, mpl=mpl,
-                                   max_events=max_events)
-
+        in_names.append(BMISS)
+        operands.append(bmiss.astype(jnp.int32)[:, None, :])
+    in_specs = [lane_block(a.shape[-1]) for a in operands]
+    scalar_out = jax.ShapeDtypeStruct((n_lanes, 1, 1), jnp.int32)
+    f32_out = jax.ShapeDtypeStruct((n_lanes, 1, 1), jnp.float32)
+    out_shape = [f32_out, scalar_out, scalar_out, f32_out]
+    out_specs = [lane_block(1)] * 4
+    ring_names, scratch_names, scratch_shapes = [], [], []
+    n_k = spec_tabs[IS_QUEUE].shape[-1]
+    job_rows = vmem_rows(mpl)
+    for name in JOB_GROUP:
+        scratch_names.append(name)
+        scratch_shapes.append(pltpu.VMEM((job_rows, LANES), jnp.int32))
+    for name, width in ((BRANCH, mpl), (POS, mpl), (BUSY, n_k)):
+        scratch_names.append(name)
+        scratch_shapes.append(pltpu.SMEM((width,), jnp.int32))
+    if trace_cap:
+        out_shape.append(scalar_out)
+        out_specs.append(lane_block(1))
+        for name, (n, dt, _) in _trace_tables(trace_cap, mpl,
+                                              route_len).items():
+            rows = vmem_rows(n)
+            if name in (SCR_ENTER, SCR_LEAVE):
+                scratch_names.append(name)
+                scratch_shapes.append(pltpu.VMEM((rows, LANES), dt))
+            else:
+                ring_names.append(name)
+                out_shape.append(
+                    jax.ShapeDtypeStruct((n_lanes, rows, LANES), dt))
+                out_specs.append(lane_rows(rows))
+    kernel = functools.partial(
+        _sim_kernel, in_names=in_names, scratch_names=scratch_names,
+        ring_names=ring_names, n_requests=n_requests, warmup=warmup, mpl=mpl,
+        max_events=max_events, route_len=route_len, trace_cap=trace_cap)
     out = pl.pallas_call(
         kernel,
         grid=(n_lanes,),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel",),
-        ),
+        scratch_shapes=scratch_shapes,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
     )(*operands)
-    return out
+    x, completed, events, t_meas = (o[:, 0, 0] for o in out[:4])
+    rings = None
+    if trace_cap:
+        tabs = dict(zip(ring_names, out[5:]))
+        rings = _rings(tabs, out[4][:, 0, 0], trace_cap, route_len)
+    return x, completed, events, t_meas, rings
 
 
 def simulate_grid_pallas(net, p_hits, n_requests: int = 40_000,
@@ -416,9 +470,18 @@ def simulate_grid_pallas(net, p_hits, n_requests: int = 40_000,
     def tile(a):
         return jnp.concatenate([a] * n_s, axis=0) if n_s > 1 else a
 
-    # drop disk_rank (index 7) and the static mpl: the closed-loop
-    # non-coalescing kernel never touches the MSHR machinery
-    spec_arrays = tuple(tile(a) for a in spec[:7])
+    # the closed-loop non-coalescing kernel never touches the MSHR
+    # machinery: drop disk_rank and the static mpl
+    n_l = int(spec.visits.shape[-1])
+    spec_tabs = {
+        IS_QUEUE: tile(spec.is_queue.astype(jnp.int32)),
+        SVC_NS: tile(spec.svc_ns),
+        DIST_ID: tile(spec.dist_id),
+        DIST_PAR: tile(spec.dist_params.reshape(n_p, -1)),
+        BRANCH_CUM: tile(spec.branch_cum),
+        VISITS: tile(spec.visits.reshape(n_p, -1)),
+        SERVERS: tile(spec.servers),
+    }
     seed_v = jnp.concatenate(
         [jnp.full((n_p,), s, jnp.int32) * 1000
          + jnp.arange(n_p, dtype=jnp.int32) for s in seeds]
@@ -426,29 +489,25 @@ def simulate_grid_pallas(net, p_hits, n_requests: int = 40_000,
     bmiss_v = None
     if trace:
         # Per-branch sojourn class, precomputed host-side (the kernel's
-        # _SpecArrays carries no disk_rank): a branch whose route touches
+        # spec tables carry no disk_rank): a branch whose route touches
         # a backing store is a miss, anything else a hit (the pallas
         # engine is closed-loop non-coalescing — no delayed hits).
         vis = np.asarray(specs[0].visits)
         dr = np.asarray(specs[0].disk_rank)
         bmiss = ((dr[np.maximum(vis, 0)] >= 0) & (vis >= 0)).any(axis=1)
         bmiss_v = jnp.asarray(
-            np.broadcast_to(bmiss, (n_p * n_s, bmiss.shape[0]))
-        )
+            np.broadcast_to(bmiss, (n_p * n_s, bmiss.shape[0])), jnp.int32)
 
-    if interpret is None and jax.default_backend() != "tpu":
-        out = _twin_grid(spec_arrays, seed_v, bmiss_v,
-                         n_requests=n_requests, warmup=warmup, mpl=net.mpl,
-                         max_events=max_events, trace_cap=trace)
-        rings = out[4] if trace else None
+    kw = dict(n_requests=n_requests, warmup=warmup, mpl=net.mpl,
+              max_events=max_events, route_len=n_l, trace_cap=trace)
+    if interpret is None and not on_tpu():
+        out = _twin_grid(spec_tabs, seed_v, bmiss_v, **kw)
     else:
-        out = _pallas_grid(
-            spec_arrays, seed_v, bmiss_v, n_requests=n_requests,
-            warmup=warmup, mpl=net.mpl, max_events=max_events,
+        out = pallas_grid(
+            spec_tabs, seed_v, bmiss_v,
             interpret=bool(interpret) if interpret is not None else False,
-            trace_cap=trace,
-        )
-        rings = TraceRings(*out[4:12]) if trace else None
+            **kw)
+    rings = out[4]
     traces = None
     if trace:
         traces = decode_trace_grid(rings, specs[0].visits, n_s, n_p)

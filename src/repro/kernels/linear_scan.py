@@ -17,7 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams
 
 
 def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, s_ref, *,
@@ -73,7 +72,7 @@ def wkv6_scan(r, k, v, w, u, *, chunk: int = 128, interpret: bool = False):
         out_specs=pl.BlockSpec((1, chunk, dh), lambda bh, ci: (bh, ci, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, T, dh), r.dtype),
         scratch_shapes=[pltpu.VMEM((dh, dh), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -1,8 +1,8 @@
 """Jitted public wrappers around the Pallas kernels.
 
-Model code calls these (layout adaptation + padding + jit); on CPU pass
-interpret=True (the kernels execute in the Pallas interpreter), on TPU the
-same calls compile to real kernels.
+Model code calls these (layout adaptation + padding + jit) with
+``interpret=not repro.kernels.on_tpu()``: on the TPU the calls compile to
+Mosaic kernels, elsewhere the kernels run in the Pallas interpreter.
 """
 
 from __future__ import annotations

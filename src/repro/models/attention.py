@@ -233,10 +233,11 @@ def attention(
     window = cfg.local_window if kind == "local" else 0
 
     if use_pallas and kv_cache is None and cross_kv is None:
+        from repro.kernels import on_tpu
         from repro.kernels import ops as kops
 
         out = kops.flash_attention(
-            q, k, v, causal=causal, window=window, interpret=True
+            q, k, v, causal=causal, window=window, interpret=not on_tpu()
         )
     else:
         chunk = min(1024, max(128, S)) if S >= 128 else S

@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.cache import classify_inflight, classify_inflight_py
 from repro.cache.py_ref import PY_POLICIES
@@ -108,6 +109,40 @@ def test_kernel_interpreter_bit_identical(policy):
             err_msg=f"{policy} {field}")
 
 
+@pytest.mark.parametrize("policy", sorted(PY_POLICIES))
+def test_kernel_interpreter_multi_block_evictions(policy):
+    """Evictions whose victim searches walk several ``BLOCK_ROWS``-row
+    blocks, with capacity below the pad: the kernel body == the twin ==
+    py_ref.  The first lane fills all three allocated blocks; the second
+    re-initialises only the two its capacity reaches, so the first lane's
+    third block is still in scratch and must stay invisible."""
+    from repro.kernels.replay import BLOCK_ROWS
+    from repro.kernels.state import LANES
+
+    pad = 3 * BLOCK_ROWS * LANES
+    caps = [9000, 5000]
+    rng = np.random.default_rng(5)
+    # distinct keys first, so both capacities fill and then evict
+    keys = np.concatenate([rng.permutation(pad)[:9500],
+                           rng.integers(0, pad, 2500)])
+    us = rng.random(keys.size, dtype=np.float32)
+    kw = dict(key_space=pad, pad_to=pad, window=16, **JAX_PARAMS[policy])
+    twin = replay_grid_pallas(policy, keys, us, caps, **kw)
+    kern = replay_grid_pallas(policy, keys, us, caps, interpret=True, **kw)
+    for field in ("hits", "evicted", "ops", "cls"):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(kern, field)),
+            np.asarray(getattr(twin, field)),
+            err_msg=f"{policy} {field}")
+    evicted = np.asarray(kern.evicted)
+    assert np.all((evicted >= 0).sum(axis=-1) > 1000)
+    for c, cap in enumerate(caps):
+        hits, ev, ops4 = _oracle(policy, cap, keys, us)
+        np.testing.assert_array_equal(np.asarray(kern.hits)[c, 0], hits)
+        np.testing.assert_array_equal(evicted[c, 0], ev)
+        np.testing.assert_array_equal(unpack_grid_ops(kern)[c, 0], ops4)
+
+
 def test_non_tile_multiple_capacity():
     """C=700-class shapes: capacity not a multiple of any tile/pad size,
     pad rounding above it, seeds > 1."""
@@ -183,6 +218,58 @@ def test_event_sim_kernel_matches_twin():
                                 interpret=True)
     np.testing.assert_array_equal(twin.throughput, kern.throughput)
     np.testing.assert_array_equal(twin.p_hit, kern.p_hit)
+
+
+def test_event_sim_traced_kernel_matches_twin():
+    """With tracing on, the kernel's record rings == the twin's."""
+    net = lru_network(disk_us=100.0)
+    kw = dict(n_requests=300, seeds=(0, 1), trace=16)
+    twin = simulate_grid_pallas(net, [0.5, 0.9], **kw)
+    kern = simulate_grid_pallas(net, [0.5, 0.9], interpret=True, **kw)
+    np.testing.assert_array_equal(twin.throughput, kern.throughput)
+    for row_t, row_k in zip(twin.traces, kern.traces):
+        for tr_t, tr_k in zip(row_t, row_k):
+            assert tr_t.n_dropped == tr_k.n_dropped > 0
+            for field in ("req", "branch", "cls", "nvis", "enter_us",
+                          "leave_us"):
+                np.testing.assert_array_equal(
+                    getattr(tr_k, field), getattr(tr_t, field),
+                    err_msg=field)
+
+
+@pytest.mark.parametrize("mask_kind", ["ties", "empty", "tail"])
+def test_indexed_state_argmin_semantics(mask_kind):
+    """RefState's blocked argmin (inside a kernel) == ArrayState's
+    jnp.argmin: first index of the least masked key across blocks, and
+    (0, INT32_MAX) when nothing is masked."""
+    from jax.experimental import pallas as pl
+
+    from repro.indexed_state import ArrayState
+    from repro.kernels.state import RefState
+
+    n = 4 * 1024
+    rng = np.random.default_rng(8)
+    key = rng.integers(0, 50, n).astype(np.int32)  # many ties
+    mask = {"ties": rng.random(n) < 0.5, "empty": np.zeros(n, bool),
+            "tail": np.arange(n) >= n - 700}[mask_kind].astype(np.int32)
+
+    def fn(v):
+        return v.aux != 0, v.ts
+
+    def kernel(ts_ref, aux_ref, out_ref):
+        st = RefState({"ts": ts_ref, "aux": aux_ref},
+                      block_rows=8, n_blocks=4)
+        idx, m = st.argmin(fn)
+        out_ref[0] = idx
+        out_ref[1] = m
+
+    got = pl.pallas_call(
+        kernel, out_shape=jax.ShapeDtypeStruct((2,), jnp.int32),
+        out_specs=pl.BlockSpec(memory_space=pltpu.SMEM), interpret=True,
+    )(jnp.asarray(key.reshape(-1, 128)), jnp.asarray(mask.reshape(-1, 128)))
+    want = ArrayState({"ts": jnp.asarray(key), "aux": jnp.asarray(mask)},
+                      "ts").argmin(fn)
+    assert [int(g) for g in got] == [int(w) for w in want]
 
 
 def test_event_sim_statistics_match_threefry():
