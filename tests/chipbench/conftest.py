@@ -1,0 +1,9 @@
+"""Puts the checkout's root on ``sys.path`` so the tests import the
+benchmark (``chipbench``) the way its runner does."""
+
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[2]
+if str(ROOT) not in sys.path:
+    sys.path.insert(0, str(ROOT))
