@@ -56,6 +56,7 @@ from repro.core.queueing import (
     Station,
     disk_station,
 )
+from repro.obs import spans
 
 
 @dataclasses.dataclass(frozen=True)
@@ -257,82 +258,89 @@ def empirical_network(
     Scan steps are charged at a dedicated queue station (an approximation of
     the paper's folding of scan time into S_tail; documented in DESIGN.md).
     """
-    service = service or PAPER_SERVICES.get(policy, ServiceTimes())
-    w = int(len(hits) * warmup_frac)
-    hits_m, ops_m = hits[w:], ops[w:]
-    # vectorized profile histogram: each (hit, op-vector) row packs into one
-    # int64 (12 bits per list-op count, 26 for the scan count — SIEVE's
-    # hand can clear thousands of bits in one eviction), so the
-    # unique+count is a scalar sort — a per-request Python Counter (and
-    # even np.unique over rows, which sorts void views) dominated sweep
-    # time at 60k requests.
-    ops64 = np.asarray(ops_m, np.int64)
-    if ops64.size and (ops64[:, :3].max() > 0xFFF
-                       or ops64[:, 3].max() > _SCAN_MASK):
-        raise ValueError("op count exceeds the profile packing")
-    code = (
-        (np.asarray(hits_m, np.int64) << 62)
-        | (ops64[:, 0] << 50) | (ops64[:, 1] << 38)
-        | (ops64[:, 2] << 26) | ops64[:, 3]
-    )
-    uniq, counts = np.unique(code, return_counts=True)
-    profiles = {
-        (bool(c >> 62), (int((c >> 50) & 0xFFF), int((c >> 38) & 0xFFF),
-                         int((c >> 26) & 0xFFF), int(c & _SCAN_MASK))): int(n)
-        for c, n in zip(uniq, counts)
-    }
-    total = int(counts.sum())
-
-    stations = [
-        Station("lookup", THINK, service.lookup, dist="det"),
-        disk_station(service.disk, disk_servers),
-        Station("delink", QUEUE, service.delink, dist="det"),
-        Station("head", QUEUE, service.head, dist="pareto",
-                dist_params=(0.45, 0.1, max(2 * service.head - 0.1, 0.2))),
-        Station("tail", QUEUE, service.tail, dist="det"),
-        Station("scan", QUEUE, service.scan, dist="det"),
-    ]
-    branches = []
-    for (hit, op_vec), count in sorted(profiles.items()):
-        n_delink, n_head, n_tail, n_scan = op_vec
-        visits = ["lookup"]
-        if not hit:
-            visits.append("disk")
-        visits += (["delink"] * n_delink + ["head"] * n_head
-                   + ["tail"] * n_tail + ["scan"] * n_scan)
-        branches.append(
-            Branch(
-                f"{'hit' if hit else 'miss'}_{op_vec}",
-                count / total,
-                tuple(visits),
+    with spans.span("harness.empirical_network",
+                    requests_count=len(hits)):
+        service = service or PAPER_SERVICES.get(policy, ServiceTimes())
+        w = int(len(hits) * warmup_frac)
+        hits_m, ops_m = hits[w:], ops[w:]
+        # vectorized profile histogram: each (hit, op-vector) row packs into
+        # one int64 (12 bits per list-op count, 26 for the scan count —
+        # SIEVE's hand can clear thousands of bits in one eviction), so the
+        # unique+count is a scalar sort — a per-request Python Counter (and
+        # even np.unique over rows, which sorts void views) dominated sweep
+        # time at 60k requests.
+        with spans.span("harness.profile"):
+            ops64 = np.asarray(ops_m, np.int64)
+            if ops64.size and (ops64[:, :3].max() > 0xFFF
+                               or ops64[:, 3].max() > _SCAN_MASK):
+                raise ValueError("op count exceeds the profile packing")
+            code = (
+                (np.asarray(hits_m, np.int64) << 62)
+                | (ops64[:, 0] << 50) | (ops64[:, 1] << 38)
+                | (ops64[:, 2] << 26) | ops64[:, 3]
             )
+            uniq, counts = np.unique(code, return_counts=True)
+            profiles = {
+                (bool(c >> 62),
+                 (int((c >> 50) & 0xFFF), int((c >> 38) & 0xFFF),
+                  int((c >> 26) & 0xFFF), int(c & _SCAN_MASK))): int(n)
+                for c, n in zip(uniq, counts)
+            }
+            total = int(counts.sum())
+
+        stations = [
+            Station("lookup", THINK, service.lookup, dist="det"),
+            disk_station(service.disk, disk_servers),
+            Station("delink", QUEUE, service.delink, dist="det"),
+            Station("head", QUEUE, service.head, dist="pareto",
+                    dist_params=(0.45, 0.1,
+                                 max(2 * service.head - 0.1, 0.2))),
+            Station("tail", QUEUE, service.tail, dist="det"),
+            Station("scan", QUEUE, service.scan, dist="det"),
+        ]
+        branches = []
+        for (hit, op_vec), count in sorted(profiles.items()):
+            n_delink, n_head, n_tail, n_scan = op_vec
+            visits = ["lookup"]
+            if not hit:
+                visits.append("disk")
+            visits += (["delink"] * n_delink + ["head"] * n_head
+                       + ["tail"] * n_tail + ["scan"] * n_scan)
+            branches.append(
+                Branch(
+                    f"{'hit' if hit else 'miss'}_{op_vec}",
+                    count / total,
+                    tuple(visits),
+                )
+            )
+        net = ClosedNetwork(
+            f"{policy}-empirical", tuple(stations), tuple(branches), mpl,
+            description=f"measured-profile network for {policy}",
         )
-    net = ClosedNetwork(
-        f"{policy}-empirical", tuple(stations), tuple(branches), mpl,
-        description=f"measured-profile network for {policy}",
-    )
 
-    # hit ratio and per-class mean op vectors straight from the histogram
-    # (equivalent to masking the raw arrays, without the large copies)
-    def mean_ops(want_hit: bool) -> np.ndarray:
-        count = sum(c for (h, _), c in profiles.items() if h == want_hit)
-        if not count:
-            return np.zeros(4)
-        acc = np.zeros(4)
-        for (h, vec), c in profiles.items():
-            if h == want_hit:
-                acc += np.asarray(vec, np.float64) * c
-        return acc / count
+        # hit ratio and per-class mean op vectors straight from the
+        # histogram (equivalent to masking the raw arrays, without the large
+        # copies)
+        def mean_ops(want_hit: bool) -> np.ndarray:
+            count = sum(c for (h, _), c in profiles.items()
+                        if h == want_hit)
+            if not count:
+                return np.zeros(4)
+            acc = np.zeros(4)
+            for (h, vec), c in profiles.items():
+                if h == want_hit:
+                    acc += np.asarray(vec, np.float64) * c
+            return acc / count
 
-    n_hits = sum(c for (h, _), c in profiles.items() if h)
-    hit_ratio = n_hits / total if total else 0.0
-    mean_hit = mean_ops(True)
-    mean_miss = mean_ops(False)
-    return CacheMeasurement(
-        policy=policy, capacity=-1, hit_ratio=hit_ratio,
-        mean_ops_hit=mean_hit, mean_ops_miss=mean_miss,
-        profiles=dict(profiles), network=net,
-    )
+        n_hits = sum(c for (h, _), c in profiles.items() if h)
+        hit_ratio = n_hits / total if total else 0.0
+        mean_hit = mean_ops(True)
+        mean_miss = mean_ops(False)
+        return CacheMeasurement(
+            policy=policy, capacity=-1, hit_ratio=hit_ratio,
+            mean_ops_hit=mean_hit, mean_ops_miss=mean_miss,
+            profiles=dict(profiles), network=net,
+        )
 
 
 def parameterized_network(

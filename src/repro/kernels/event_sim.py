@@ -48,6 +48,7 @@ from repro.core.simspec import (BIG_SEQ, INF_NS, SimResult, compile_network,
 from repro.kernels import on_tpu
 from repro.indexed_state import ArrayState
 from repro.kernels.state import LANES, RefState, vmem_rows
+from repro.obs import spans
 from repro.obs.trace import CLS_HIT, CLS_MISS, TraceRings, decode_trace_grid
 
 _GOLDEN = np.uint32(0x9E3779B9)
@@ -460,60 +461,69 @@ def simulate_grid_pallas(net, p_hits, n_requests: int = 40_000,
     tracing at all.
     """
     p_hits = np.atleast_1d(np.asarray(p_hits, dtype=np.float64))
-    specs = [compile_network(net, float(p)) for p in p_hits]
-    spec = stack_specs(specs)
+    specs = []
+    for p in p_hits:
+        with spans.span("sim.compile_network"):
+            specs.append(compile_network(net, float(p)))
     warmup = int(n_requests * warmup_frac)
-    max_events = int(n_requests * (spec.visits.shape[-1] + 2) * 3)
     n_p, n_s = len(p_hits), len(seeds)
     trace = int(trace)
 
     def tile(a):
         return jnp.concatenate([a] * n_s, axis=0) if n_s > 1 else a
 
-    # the closed-loop non-coalescing kernel never touches the MSHR
-    # machinery: drop disk_rank and the static mpl
-    n_l = int(spec.visits.shape[-1])
-    spec_tabs = {
-        IS_QUEUE: tile(spec.is_queue.astype(jnp.int32)),
-        SVC_NS: tile(spec.svc_ns),
-        DIST_ID: tile(spec.dist_id),
-        DIST_PAR: tile(spec.dist_params.reshape(n_p, -1)),
-        BRANCH_CUM: tile(spec.branch_cum),
-        VISITS: tile(spec.visits.reshape(n_p, -1)),
-        SERVERS: tile(spec.servers),
-    }
-    seed_v = jnp.concatenate(
-        [jnp.full((n_p,), s, jnp.int32) * 1000
-         + jnp.arange(n_p, dtype=jnp.int32) for s in seeds]
-    )
-    bmiss_v = None
-    if trace:
-        # Per-branch sojourn class, precomputed host-side (the kernel's
-        # spec tables carry no disk_rank): a branch whose route touches
-        # a backing store is a miss, anything else a hit (the pallas
-        # engine is closed-loop non-coalescing — no delayed hits).
-        vis = np.asarray(specs[0].visits)
-        dr = np.asarray(specs[0].disk_rank)
-        bmiss = ((dr[np.maximum(vis, 0)] >= 0) & (vis >= 0)).any(axis=1)
-        bmiss_v = jnp.asarray(
-            np.broadcast_to(bmiss, (n_p * n_s, bmiss.shape[0])), jnp.int32)
+    with spans.span("sim.tables"):
+        spec = stack_specs(specs)
+        max_events = int(n_requests * (spec.visits.shape[-1] + 2) * 3)
+        # the closed-loop non-coalescing kernel never touches the MSHR
+        # machinery: drop disk_rank and the static mpl
+        n_l = int(spec.visits.shape[-1])
+        spec_tabs = {
+            IS_QUEUE: tile(spec.is_queue.astype(jnp.int32)),
+            SVC_NS: tile(spec.svc_ns),
+            DIST_ID: tile(spec.dist_id),
+            DIST_PAR: tile(spec.dist_params.reshape(n_p, -1)),
+            BRANCH_CUM: tile(spec.branch_cum),
+            VISITS: tile(spec.visits.reshape(n_p, -1)),
+            SERVERS: tile(spec.servers),
+        }
+        seed_v = jnp.concatenate(
+            [jnp.full((n_p,), s, jnp.int32) * 1000
+             + jnp.arange(n_p, dtype=jnp.int32) for s in seeds]
+        )
+        bmiss_v = None
+        if trace:
+            # Per-branch sojourn class, precomputed host-side (the kernel's
+            # spec tables carry no disk_rank): a branch whose route touches
+            # a backing store is a miss, anything else a hit (the pallas
+            # engine is closed-loop non-coalescing — no delayed hits).
+            vis = np.asarray(specs[0].visits)
+            dr = np.asarray(specs[0].disk_rank)
+            bmiss = ((dr[np.maximum(vis, 0)] >= 0) & (vis >= 0)).any(axis=1)
+            bmiss_v = jnp.asarray(
+                np.broadcast_to(bmiss, (n_p * n_s, bmiss.shape[0])),
+                jnp.int32)
 
     kw = dict(n_requests=n_requests, warmup=warmup, mpl=net.mpl,
               max_events=max_events, route_len=n_l, trace_cap=trace)
-    if interpret is None and not on_tpu():
-        out = _twin_grid(spec_tabs, seed_v, bmiss_v, **kw)
-    else:
-        out = pallas_grid(
-            spec_tabs, seed_v, bmiss_v,
-            interpret=bool(interpret) if interpret is not None else False,
-            **kw)
-    rings = out[4]
-    traces = None
-    if trace:
-        traces = decode_trace_grid(rings, specs[0].visits, n_s, n_p)
-    xs = np.asarray(out[0]).reshape(n_s, n_p)
-    mean = xs.mean(axis=0)
-    ci = (1.96 * xs.std(axis=0, ddof=1) / math.sqrt(n_s) if n_s > 1
-          else np.zeros_like(mean))
-    return SimResult(p_hit=p_hits, throughput=mean, ci95=ci,
-                     n_requests=n_requests, traces=traces)
+    with spans.span("sim.launch"):
+        if interpret is None and not on_tpu():
+            out = _twin_grid(spec_tabs, seed_v, bmiss_v, **kw)
+        else:
+            out = pallas_grid(
+                spec_tabs, seed_v, bmiss_v,
+                interpret=bool(interpret) if interpret is not None else False,
+                **kw)
+    with spans.span("sim.collect") as sp:
+        rings = out[4]
+        traces = None
+        if trace:
+            traces = decode_trace_grid(rings, specs[0].visits, n_s, n_p)
+        xs = np.asarray(out[0]).reshape(n_s, n_p)
+        mean = xs.mean(axis=0)
+        ci = (1.96 * xs.std(axis=0, ddof=1) / math.sqrt(n_s) if n_s > 1
+              else np.zeros_like(mean))
+        if spans.recording():
+            sp.count(events_count=int(np.asarray(out[2], np.int64).sum()))
+        return SimResult(p_hit=p_hits, throughput=mean, ci95=ci,
+                         n_requests=n_requests, traces=traces)

@@ -63,6 +63,7 @@ from repro.cache.replay import (DELAYED_HIT, TRUE_HIT, TRUE_MISS, _FAR_PAST,
 from repro.cache.policies import _padded
 from repro.kernels import on_tpu
 from repro.kernels.state import LANES, RefState, vmem_block_rows, vmem_rows
+from repro.obs import spans
 
 
 class PallasReplayResult(NamedTuple):
@@ -301,33 +302,41 @@ def replay_grid_pallas(policy: str, keys, us, capacities, *,
     (same step functions, one dispatch).  ``True`` forces the pallas
     interpreter (the kernel body itself, run on CPU — the CI fallback).
     """
-    (keys_l, us_l, pvecs_l, qs_l, key_space, pad,
-     n_caps, n_s) = _lane_inputs(policy, keys, us, capacities, key_space,
-                                 pad_to, params)
-    win_l = np.broadcast_to(
-        _window_stream(window, keys_l.shape[1], fail_prob, fail_seed),
-        keys_l.shape,
-    )
-    args = (jnp.asarray(pvecs_l), jnp.asarray(qs_l), jnp.asarray(keys_l),
-            jnp.asarray(us_l), jnp.asarray(win_l))
-    if interpret is None and not on_tpu():
-        hits, evicted, ops, cls = _twin_grid(
-            policy, *args, key_space=key_space, pad=pad
+    with spans.span("replay.lane_inputs") as sp:
+        (keys_l, us_l, pvecs_l, qs_l, key_space, pad,
+         n_caps, n_s) = _lane_inputs(policy, keys, us, capacities, key_space,
+                                     pad_to, params)
+        win_l = np.broadcast_to(
+            _window_stream(window, keys_l.shape[1], fail_prob, fail_seed),
+            keys_l.shape,
         )
-    else:
-        hits, evicted, ops, cls = pallas_grid(
-            policy, *args, key_space=key_space, pad=pad,
-            interpret=bool(interpret) if interpret is not None else False,
+        sp.count(lanes_count=keys_l.shape[0], requests_count=keys_l.shape[1])
+    with spans.span("replay.h2d") as sp:
+        args = tuple(jnp.asarray(a)
+                     for a in (pvecs_l, qs_l, keys_l, us_l, win_l))
+        sp.count(h2d_bytes=sum(a.nbytes for a in args))
+    with spans.span("replay.launch"):
+        if interpret is None and not on_tpu():
+            hits, evicted, ops, cls = _twin_grid(
+                policy, *args, key_space=key_space, pad=pad
+            )
+        else:
+            hits, evicted, ops, cls = pallas_grid(
+                policy, *args, key_space=key_space, pad=pad,
+                interpret=bool(interpret) if interpret is not None else False,
+            )
+        shape = (n_caps, n_s, keys_l.shape[1])
+        return PallasReplayResult(
+            hits=hits.reshape(shape),
+            evicted=evicted.reshape(shape),
+            ops=ops.reshape(shape),
+            cls=cls.reshape(shape) if window is not None else None,
         )
-    shape = (n_caps, n_s, keys_l.shape[1])
-    return PallasReplayResult(
-        hits=hits.reshape(shape),
-        evicted=evicted.reshape(shape),
-        ops=ops.reshape(shape),
-        cls=cls.reshape(shape) if window is not None else None,
-    )
 
 
 def unpack_grid_ops(res: PallasReplayResult) -> np.ndarray:
     """Host-side (C, S, T, 4) int64 op counts, matching ReplayResult.ops."""
-    return np.asarray(flat.unpack_ops(res.ops), np.int64)
+    with spans.span("replay.unpack") as sp:
+        ops = flat.unpack_ops(res.ops)
+        sp.count(d2h_bytes=ops.nbytes)
+        return np.asarray(ops, np.int64)

@@ -22,6 +22,9 @@ cache.  This package is that instrument for the reproduction:
 * :mod:`repro.obs.streaming` — fixed-shape in-kernel streaming
   estimators (windowed/EWMA rates, count-min + SpaceSaving popularity
   sketch) threaded through the simulators behind ``sketch_cap=0``.
+* :mod:`repro.obs.spans` — host spans of the program's own steps
+  (lane set-up, transfers, decode, network compilation) with their
+  counts, written into the JAX profiler's trace on the device's clock.
 * :mod:`repro.obs.drift` — CUSUM / Page-Hinkley sequential change
   detectors over the estimator series.
 * :mod:`repro.obs.profile` / :mod:`repro.obs.residuals` — online

@@ -4,6 +4,8 @@ import functools
 
 import jax
 
+from repro.obs import spans
+
 
 class BadSchema:
     parked: float = 0.0  # obs-units: time-like field without a unit
@@ -46,3 +48,11 @@ def emit(metrics):
     metrics.count("events_count")  # clean: counter suffix
     metrics.gauge("depth_count", 1.0)  # clean
     metrics.observe("sojourn_us", 2.0)  # clean
+
+
+def span_counts(n):
+    with spans.span("replay.h2d", lanes=n) as sp:  # obs-units: span count without suffix
+        sp.count(h2d=n)  # obs-units: span count call without suffix
+        sp.count(h2d_bytes=n)  # clean: byte suffix
+    with spans.span("replay.lane_inputs", lanes_count=n):  # clean: counted
+        pass

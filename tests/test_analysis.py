@@ -217,6 +217,15 @@ class TestObsLint:
                 if v.rule == "obs-ring-static" and v.line == want]
         assert len(hits) == 1 and "window_us" in hits[0].message
 
+    def test_span_count_without_suffix(self, found):
+        path = self.fixture_path()
+        for marker in ("# obs-units: span count without suffix",
+                       "# obs-units: span count call without suffix"):
+            want = line_of(path, marker)
+            hits = [v for v in found
+                    if v.rule == "obs-units" and v.line == want]
+            assert len(hits) == 1 and "span count" in hits[0].message, marker
+
     def test_clean_lines_stay_clean(self, found):
         path = self.fixture_path()
         text = path.read_text().splitlines()

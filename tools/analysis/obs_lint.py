@@ -14,6 +14,11 @@ Two rules over ``src/repro``:
     a trace schema whose timestamps don't say their unit is how µs/ns
     bugs get in.
 
+``obs-units`` also covers the counts of the program's host spans: each
+keyword name passed to ``repro.obs.spans.span(...)``, or to ``.count(...)``
+on the span it yields (``with spans.span(...) as sp: sp.count(...)``),
+carries a unit suffix.
+
 ``obs-units`` additionally covers estimator state: fields of
 ``repro.obs`` schema classes whose stem marks them as windowed or EWMA
 estimator state (``win``/``window``/``ewma``) must say what they hold —
@@ -93,6 +98,51 @@ def _check_metric_calls(src: SourceFile) -> List[Violation]:
                 f"metric name {name_arg.value!r} lacks a unit suffix "
                 f"({', '.join(UNIT_SUFFIXES)}) — see repro.obs.metrics",
             ))
+    return out
+
+
+def _span_modules(tree: ast.AST) -> set:
+    """Names bound to the ``repro.obs.spans`` module."""
+    return {a.asname or a.name for node in ast.walk(tree)
+            if isinstance(node, ast.ImportFrom) and node.module == "repro.obs"
+            for a in node.names if a.name == "spans"}
+
+
+def _check_span_counts(src: SourceFile) -> List[Violation]:
+    """Count names given to a span, when it opens or through ``count``."""
+    out: List[Violation] = []
+    assert src.tree is not None
+    modules = _span_modules(src.tree)
+    if not modules:
+        return out
+
+    def opens_span(call: ast.AST) -> bool:
+        fn = getattr(call, "func", None)
+        return (isinstance(call, ast.Call) and isinstance(fn, ast.Attribute)
+                and fn.attr == "span" and isinstance(fn.value, ast.Name)
+                and fn.value.id in modules)
+
+    handles = {item.optional_vars.id
+               for node in ast.walk(src.tree)
+               if isinstance(node, (ast.With, ast.AsyncWith))
+               for item in node.items
+               if opens_span(item.context_expr)
+               and isinstance(item.optional_vars, ast.Name)}
+    for node in ast.walk(src.tree):
+        fn = getattr(node, "func", None)
+        if not (opens_span(node)
+                or (isinstance(node, ast.Call)
+                    and isinstance(fn, ast.Attribute) and fn.attr == "count"
+                    and isinstance(fn.value, ast.Name)
+                    and fn.value.id in handles)):
+            continue
+        for kw in node.keywords:
+            if kw.arg is not None and not _has_unit_suffix(kw.arg):
+                out.append(Violation(
+                    "obs-units", src.path, node.lineno,
+                    f"span count {kw.arg!r} lacks a unit suffix "
+                    f"({', '.join(UNIT_SUFFIXES)}) — see repro.obs.spans",
+                ))
     return out
 
 
@@ -204,9 +254,10 @@ def run(
             continue
         checked += 1
         violations.extend(_check_metric_calls(src))
+        violations.extend(_check_span_counts(src))
         violations.extend(_check_ring_static(src))
         if str(path).startswith(str(obs_dir)):
             violations.extend(_check_schema_fields(src))
-    notes = [Note(f"obs-lint: {checked} files (metric suffixes, trace-ring "
-                  f"static shapes)")]
+    notes = [Note(f"obs-lint: {checked} files (metric and span-count "
+                  f"suffixes, trace-ring static shapes)")]
     return violations, notes
