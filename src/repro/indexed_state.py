@@ -2,16 +2,23 @@
 
 The replay policies (:mod:`repro.cache.flat`) and the event loop
 (:mod:`repro.kernels.event_sim`) each write their step ONCE against this
-small interface, and run on two implementations of it:
+small interface, and run on three implementations of it:
 
 :class:`ArrayState`  named jnp arrays, functional updates (``.at[].set``) —
                      the vmapped ``lax.scan`` / ``while_loop`` twins that
                      run off-TPU;
 :class:`~repro.kernels.state.RefState`
                      named Pallas refs, in-place loads and stores of the
-                     touched slots only — the kernel bodies.
+                     touched slots only — the kernel bodies that run one
+                     lane per grid cell (the replay kernel, the traced
+                     event kernel);
+:class:`~repro.kernels.state.LaneRefState`
+                     named Pallas refs holding up to 128 lanes, one per
+                     vector lane; indices and scalars are ``(1, 128)``
+                     rows and control flow follows ``vmap``'s batching
+                     rule — the lane-packed event kernel.
 
-Because the step code is shared, the twin and the kernel agree by
+Because the step code is shared, the twin and the kernels agree by
 construction.  The interface is:
 
 ``get(name, i)`` / ``set(name, i, v)`` / ``set_if(name, i, c, v)``

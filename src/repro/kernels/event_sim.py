@@ -18,13 +18,20 @@ if you ask the pallas backend for them).
 
 Because the RNG stream differs, agreement with ``simulate_network`` is
 *statistical* (same network, same mean/dispersion laws — pinned within a
-few percent by tests), while the pallas kernel and the vmapped twin share
+few percent by tests), while the pallas kernels and the vmapped twin share
 :func:`_sim_lane` verbatim and are therefore bit-identical, the same
 twin-pair structure as the replay kernel: the lane is written once
-against the indexed-state interface of :mod:`repro.indexed_state`, run on
-an ``ArrayState`` by the twin and on a ``RefState`` by the kernel (one
-grid cell per lane; job tables in VMEM rows, spec tables, per-job
-scalars and busy counts in SMEM, transcendentals on the vector unit).
+against the indexed-state interface of :mod:`repro.indexed_state` and run
+on an ``ArrayState`` by the twin and on one of two kernel layouts:
+
+* lane-packed (:func:`_lane_grid`, untraced grids): a ``LaneRefState``,
+  ``grid=(ceil(lanes / 128),)``, each cell a block of up to 128 lanes,
+  one per vector lane — every table ``(rows, 128)`` in VMEM with element
+  ``i`` of lane ``l`` at ``[i, l]``, per-lane scalars ``(1, 128)`` rows,
+  the loop run while any lane of the block is active;
+* per lane (``trace_cap > 0``): a ``RefState``, one grid cell per lane;
+  job tables and the record rings in VMEM rows, spec tables, per-job
+  scalars and busy counts in SMEM, transcendentals on the vector unit.
 
 ``interpret=None`` auto-selects: real kernel on TPU, jitted vmapped twin
 on CPU; ``interpret=True`` runs the kernel body under the pallas
@@ -47,7 +54,7 @@ from repro.core.simspec import (BIG_SEQ, INF_NS, SimResult, compile_network,
                                 stack_specs)
 from repro.kernels import on_tpu
 from repro.indexed_state import ArrayState
-from repro.kernels.state import LANES, RefState, vmem_rows
+from repro.kernels.state import LANES, LaneRefState, RefState, vmem_rows
 from repro.obs import spans
 from repro.obs.trace import CLS_HIT, CLS_MISS, TraceRings, decode_trace_grid
 
@@ -322,20 +329,19 @@ def _twin_grid(spec_tabs, seeds, bmiss=None, *, n_requests: int,
     (x, completed, events, t_meas, n_traced), tr = jax.vmap(
         lane, in_axes=(0, 0, in_bm))(spec_tabs, seeds, bmiss)
     rings = _rings(tr, n_traced, trace_cap, route_len) if trace_cap else None
-    return x, completed, events, t_meas, rings
+    return x, completed, events, t_meas, rings, None
 
 
 def _sim_kernel(*refs, in_names, scratch_names, ring_names, n_requests: int,
                 warmup: int, mpl: int, max_events: int, route_len: int,
                 trace_cap: int):
-    """One grid cell = one (p_hit, seed) lane's full event loop.
+    """One grid cell = one (p_hit, seed) lane's full event loop, traced.
 
-    Refs arrive as the named inputs, the four per-lane results (then,
-    when tracing, the record count and the ring tables) and the named
-    scratch tables.
+    Refs arrive as the named inputs, the four per-lane results, the
+    record count, the ring tables and the named scratch tables.
     """
     n_in = len(in_names)
-    n_out = 4 + (1 + len(ring_names) if trace_cap else 0)
+    n_out = 5 + len(ring_names)
     outs = refs[n_in:n_in + n_out]
     tables = {**dict(zip(in_names, refs[:n_in])),
               **dict(zip(scratch_names, refs[n_in + n_out:])),
@@ -346,11 +352,10 @@ def _sim_kernel(*refs, in_names, scratch_names, ring_names, n_requests: int,
     for k in range(tables[BUSY].shape[0]):
         tables[BUSY][k] = np.int32(0)
     vmem = JOB_GROUP
-    if trace_cap:
-        for name, (_, dt, fill) in _trace_tables(trace_cap, mpl,
-                                                 route_len).items():
-            tables[name][...] = jnp.full(tables[name].shape, fill, dt)
-            vmem += (name,)
+    for name, (_, dt, fill) in _trace_tables(trace_cap, mpl,
+                                             route_len).items():
+        tables[name][...] = jnp.full(tables[name].shape, fill, dt)
+        vmem += (name,)
     st = RefState(tables, smem=[k for k in tables if k not in vmem],
                   block_rows=tables[READY].shape[0], n_blocks=1)
     _, x, completed, events, t_meas, n_traced = _sim_lane(
@@ -358,6 +363,89 @@ def _sim_kernel(*refs, in_names, scratch_names, ring_names, n_requests: int,
         max_events=max_events, route_len=route_len, trace_cap=trace_cap)
     for ref, v in zip(outs, (x, completed, events, t_meas, n_traced)):
         ref[0, 0] = v
+
+
+def _lane_kernel(*refs, in_names, scratch_names, sizes, n_lanes: int,
+                 n_requests: int, warmup: int, mpl: int, max_events: int,
+                 route_len: int):
+    """One grid cell = the event loop of a block of up to 128 lanes, one
+    lane per vector lane (LaneRefState: every table ``(rows, 128)``).
+
+    Refs arrive as the named inputs, the five per-block results (x,
+    completed, events, t_measured_us as ``(1, 128)`` rows, then the loop's
+    trip count) and the named scratch tables.  Lanes past ``n_lanes`` (the
+    last block's padding) start inactive.
+    """
+    n_in = len(in_names)
+    outs = refs[n_in:n_in + 5]
+    tables = {**dict(zip(in_names, refs[:n_in])),
+              **dict(zip(scratch_names, refs[n_in + 5:]))}
+    seed = tables.pop("seed")[...]
+    for name in scratch_names:
+        ref = tables[name]
+        ref[...] = jnp.full(ref.shape, _JOB_PAD.get(name, 0), jnp.int32)
+    lane = (pl.program_id(0) * LANES
+            + jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1))
+    st = LaneRefState(tables, vec=READY, mask=lane < n_lanes, sizes=sizes)
+    st, x, completed, events, t_meas, _ = _sim_lane(
+        st, seed, n_requests=n_requests, warmup=warmup, mpl=mpl,
+        max_events=max_events, route_len=route_len)
+    for ref, v in zip(outs, (x, completed, events, t_meas, st.trips)):
+        ref[...] = jnp.broadcast_to(v, (1, LANES)).astype(ref.dtype)
+
+
+def _rows8(n: int) -> int:
+    """Rows of a lane-packed table of ``n`` elements: whole (8, 128) tiles."""
+    return -(-int(n) // 8) * 8
+
+
+def _lane_grid(spec_tabs, seeds, *, n_requests: int, warmup: int, mpl: int,
+               max_events: int, route_len: int, interpret: bool):
+    """The lane-packed launch: ``grid=(ceil(lanes / 128),)``, each cell one
+    128-lane block.  Returns (x, completed, events, t_measured_us) per lane
+    and the loop's trip count per block."""
+    n_lanes = seeds.shape[0]
+    n_blocks = -(-n_lanes // LANES)
+    pad = n_blocks * LANES - n_lanes
+
+    def pack(a, rows):
+        # (lanes, width) -> (blocks, rows, 128); padded lanes copy the last
+        # real lane, so their log and pow stay finite
+        if pad:
+            a = a[np.minimum(np.arange(n_blocks * LANES), n_lanes - 1)]
+        a = jnp.pad(a, ((0, 0), (0, rows - a.shape[1])))
+        return a.reshape(n_blocks, LANES, rows).transpose(0, 2, 1)
+
+    def block(rows):
+        return pl.BlockSpec((None, rows, LANES), lambda i: (i, 0, 0))
+
+    sizes = {k: spec_tabs[k].shape[1] for k in SPEC_TABLES}
+    in_names = list(SPEC_TABLES) + ["seed"]
+    operands = [pack(spec_tabs[k], _rows8(sizes[k])) for k in SPEC_TABLES]
+    operands.append(pack(seeds.astype(jnp.int32)[:, None], 1))
+    job_rows = _rows8(mpl)
+    scratch = {name: job_rows for name in JOB_GROUP + (BRANCH, POS)}
+    scratch[BUSY] = _rows8(sizes[IS_QUEUE])
+    row = jax.ShapeDtypeStruct((n_blocks, 1, LANES), jnp.int32)
+    f32_row = jax.ShapeDtypeStruct((n_blocks, 1, LANES), jnp.float32)
+    kernel = functools.partial(
+        _lane_kernel, in_names=in_names, scratch_names=list(scratch),
+        sizes=sizes, n_lanes=n_lanes, n_requests=n_requests, warmup=warmup,
+        mpl=mpl, max_events=max_events, route_len=route_len)
+    out = pl.pallas_call(
+        kernel,
+        grid=(n_blocks,),
+        in_specs=[block(a.shape[1]) for a in operands],
+        out_specs=[block(1)] * 5,
+        out_shape=[f32_row, row, row, f32_row, row],
+        scratch_shapes=[pltpu.VMEM((rows, LANES), jnp.int32)
+                        for rows in scratch.values()],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+    )(*operands)
+    per_lane = tuple(o.reshape(-1)[:n_lanes] for o in out[:4])
+    return per_lane, out[4][:, 0, 0]
 
 
 @functools.partial(jax.jit,
@@ -371,10 +459,22 @@ def pallas_grid(spec_tabs, seeds, bmiss=None, *, n_requests: int,
 
     ``spec_tabs`` maps the SPEC_TABLES names to (lanes, width) arrays,
     ``seeds`` is (lanes,) int32.  Returns (x, completed, events,
-    t_measured_us, rings) per lane — rings None unless ``trace_cap``.
+    t_measured_us, rings, lane_steps): the first four per lane, rings
+    None unless ``trace_cap``, lane_steps the lane-packed loop's trip
+    count per 128-lane block (None on the per-lane kernel).  Without
+    tracing the lanes run packed (:func:`_lane_grid`), at every lane
+    count: one packed step costs less than one lane's event on the
+    per-lane kernel.  ``trace_cap > 0`` runs one lane per grid cell, each
+    writing its own ring rows, which the packed layout would turn into a
+    pass over a whole table per write.
     Jitted, so ``pallas_grid.lower(...)`` AOT-compiles the kernel alone.
     """
     n_lanes = seeds.shape[0]
+    if not trace_cap:
+        per_lane, lane_steps = _lane_grid(
+            spec_tabs, seeds, n_requests=n_requests, warmup=warmup, mpl=mpl,
+            max_events=max_events, route_len=route_len, interpret=interpret)
+        return (*per_lane, None, lane_steps)
 
     def lane_block(width):
         return pl.BlockSpec((None, 1, width), lambda i: (i, 0, 0),
@@ -383,17 +483,15 @@ def pallas_grid(spec_tabs, seeds, bmiss=None, *, n_requests: int,
     def lane_rows(rows):
         return pl.BlockSpec((None, rows, LANES), lambda i: (i, 0, 0))
 
-    in_names = list(SPEC_TABLES) + ["seed"]
+    in_names = list(SPEC_TABLES) + ["seed", BMISS]
     operands = [spec_tabs[k][:, None, :] for k in SPEC_TABLES]
     operands.append(seeds.astype(jnp.int32)[:, None, None])
-    if trace_cap:
-        in_names.append(BMISS)
-        operands.append(bmiss.astype(jnp.int32)[:, None, :])
+    operands.append(bmiss.astype(jnp.int32)[:, None, :])
     in_specs = [lane_block(a.shape[-1]) for a in operands]
     scalar_out = jax.ShapeDtypeStruct((n_lanes, 1, 1), jnp.int32)
     f32_out = jax.ShapeDtypeStruct((n_lanes, 1, 1), jnp.float32)
-    out_shape = [f32_out, scalar_out, scalar_out, f32_out]
-    out_specs = [lane_block(1)] * 4
+    out_shape = [f32_out, scalar_out, scalar_out, f32_out, scalar_out]
+    out_specs = [lane_block(1)] * 5
     ring_names, scratch_names, scratch_shapes = [], [], []
     n_k = spec_tabs[IS_QUEUE].shape[-1]
     job_rows = vmem_rows(mpl)
@@ -403,20 +501,17 @@ def pallas_grid(spec_tabs, seeds, bmiss=None, *, n_requests: int,
     for name, width in ((BRANCH, mpl), (POS, mpl), (BUSY, n_k)):
         scratch_names.append(name)
         scratch_shapes.append(pltpu.SMEM((width,), jnp.int32))
-    if trace_cap:
-        out_shape.append(scalar_out)
-        out_specs.append(lane_block(1))
-        for name, (n, dt, _) in _trace_tables(trace_cap, mpl,
-                                              route_len).items():
-            rows = vmem_rows(n)
-            if name in (SCR_ENTER, SCR_LEAVE):
-                scratch_names.append(name)
-                scratch_shapes.append(pltpu.VMEM((rows, LANES), dt))
-            else:
-                ring_names.append(name)
-                out_shape.append(
-                    jax.ShapeDtypeStruct((n_lanes, rows, LANES), dt))
-                out_specs.append(lane_rows(rows))
+    for name, (n, dt, _) in _trace_tables(trace_cap, mpl,
+                                          route_len).items():
+        rows = vmem_rows(n)
+        if name in (SCR_ENTER, SCR_LEAVE):
+            scratch_names.append(name)
+            scratch_shapes.append(pltpu.VMEM((rows, LANES), dt))
+        else:
+            ring_names.append(name)
+            out_shape.append(
+                jax.ShapeDtypeStruct((n_lanes, rows, LANES), dt))
+            out_specs.append(lane_rows(rows))
     kernel = functools.partial(
         _sim_kernel, in_names=in_names, scratch_names=scratch_names,
         ring_names=ring_names, n_requests=n_requests, warmup=warmup, mpl=mpl,
@@ -433,11 +528,9 @@ def pallas_grid(spec_tabs, seeds, bmiss=None, *, n_requests: int,
         interpret=interpret,
     )(*operands)
     x, completed, events, t_meas = (o[:, 0, 0] for o in out[:4])
-    rings = None
-    if trace_cap:
-        tabs = dict(zip(ring_names, out[5:]))
-        rings = _rings(tabs, out[4][:, 0, 0], trace_cap, route_len)
-    return x, completed, events, t_meas, rings
+    rings = _rings(dict(zip(ring_names, out[5:])), out[4][:, 0, 0],
+                   trace_cap, route_len)
+    return x, completed, events, t_meas, rings, None
 
 
 def simulate_grid_pallas(net, p_hits, n_requests: int = 40_000,
@@ -524,6 +617,11 @@ def simulate_grid_pallas(net, p_hits, n_requests: int = 40_000,
         ci = (1.96 * xs.std(axis=0, ddof=1) / math.sqrt(n_s) if n_s > 1
               else np.zeros_like(mean))
         if spans.recording():
-            sp.count(events_count=int(np.asarray(out[2], np.int64).sum()))
+            counts = {"events_count": int(np.asarray(out[2], np.int64).sum())}
+            if out[5] is not None:
+                # vector steps of the lane-packed loop, summed over blocks
+                counts["lane_steps_count"] = int(
+                    np.asarray(out[5], np.int64).sum())
+            sp.count(**counts)
         return SimResult(p_hit=p_hits, throughput=mean, ci95=ci,
                          n_requests=n_requests, traces=traces)

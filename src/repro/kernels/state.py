@@ -22,6 +22,7 @@ from typing import Any, Callable, Dict, Iterable, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 
@@ -184,3 +185,147 @@ class RefState:
         out = lax.while_loop(lambda x: c(self, x), lambda x: b(self, x)[1],
                              init)
         return self, out
+
+
+def _static(i: Any) -> bool:
+    return isinstance(i, (int, np.integer))
+
+
+class _LaneView:
+    """Elementwise view of the vector group: whole ``(rows, 128)`` tables,
+    ``slot`` the row (element) index."""
+
+    def __init__(self, refs: Dict[str, Any], shape: Tuple[int, int]):
+        self._refs = refs
+        self._shape = shape
+
+    def __getattr__(self, name: str) -> jax.Array:
+        if name == "slot":
+            return lax.broadcasted_iota(jnp.int32, self._shape, 0)
+        return self._refs[name][...]
+
+
+class LaneRefState:
+    """Lane-packed implementation over Pallas refs: one step of up to 128
+    lanes at once, one lane per vector lane.
+
+    Every table is a VMEM ref ``(rows, 128)``: element ``i`` of lane ``l``
+    lives at ``[i, l]``.  A per-lane scalar is a ``(1, 128)`` value; an index
+    is either such a value (a one-hot row select) or a static int (one
+    row).  ``sizes`` gives a table's element count where its rows are
+    padded past it (``count_less`` ignores the padding rows); ``vec`` names
+    a member of the vector group.
+
+    Control flow follows ``vmap``'s batching rule, so the step code agrees
+    with its vmapped :class:`~repro.indexed_state.ArrayState` twin by
+    construction: the state carries ``mask``, the active lanes; every
+    store and every carry is selected by it; ``cond`` runs both branches,
+    under ``mask & pred`` and ``mask & ~pred``; ``while_loop`` iterates
+    while any lane is active, with ``mask = mask & c``, and records its
+    trip count in the returned state's ``trips``.
+    """
+
+    def __init__(self, refs: Dict[str, Any], *, vec: str, mask: Any,
+                 sizes: Dict[str, int] | None = None, trips: Any = None):
+        self.refs = refs
+        self.vec = vec
+        self.mask = mask
+        self.sizes = sizes or {}
+        self.trips = trips
+
+    def _with(self, mask: Any, trips: Any = None) -> "LaneRefState":
+        return LaneRefState(self.refs, vec=self.vec, mask=mask,
+                            sizes=self.sizes, trips=trips)
+
+    @staticmethod
+    def _rows(ref: Any) -> jax.Array:
+        return lax.broadcasted_iota(jnp.int32, ref.shape, 0)
+
+    # -- element access ----------------------------------------------------
+
+    def get(self, name: str, i: Any) -> jax.Array:
+        ref = self.refs[name]
+        if _static(i):
+            return ref[pl.ds(int(i), 1), :]
+        sel = self._rows(ref) == i
+        return jnp.sum(jnp.where(sel, ref[...], jnp.zeros((), ref.dtype)),
+                       axis=0, keepdims=True)
+
+    def set(self, name: str, i: Any, v: Any) -> "LaneRefState":
+        return self.set_if(name, i, True, v)
+
+    def set_if(self, name: str, i: Any, c: Any, v: Any) -> "LaneRefState":
+        ref = self.refs[name]
+        v = jnp.asarray(v, ref.dtype)
+        on = self.mask & c
+        if _static(i):
+            rows = pl.ds(int(i), 1)
+            ref[rows, :] = jnp.where(on, v, ref[rows, :])
+        else:
+            ref[...] = jnp.where((self._rows(ref) == i) & on, v, ref[...])
+        return self
+
+    # -- vector group ------------------------------------------------------
+
+    def _view(self) -> _LaneView:
+        return _LaneView(self.refs, self.refs[self.vec].shape)
+
+    def argmin(self, fn: Callable) -> Tuple[jax.Array, jax.Array]:
+        view = self._view()
+        mask, key = fn(view)
+        vals = jnp.where(mask, i32(key), INT32_MAX)
+        m = jnp.min(vals, axis=0, keepdims=True)
+        idx = jnp.min(jnp.where(vals == m, view.slot, INT32_MAX), axis=0,
+                      keepdims=True)
+        return jnp.where(m == INT32_MAX, 0, idx), m
+
+    def update(self, name: str, fn: Callable) -> Tuple["LaneRefState", Any]:
+        ref = self.refs[name]
+        pred, value = fn(self._view())
+        pred = jnp.broadcast_to(pred, ref.shape)
+        ref[...] = jnp.where(pred & self.mask,
+                             jnp.asarray(value, ref.dtype), ref[...])
+        return self, jnp.sum(i32(pred), axis=0, keepdims=True)
+
+    def count_less(self, name: str, x: Any) -> jax.Array:
+        ref = self.refs[name]
+        rows = self._rows(ref)
+        live = rows < self.sizes.get(name, ref.shape[0])
+        return jnp.sum(i32(live & (ref[...] < x)), axis=0, keepdims=True)
+
+    def vmath(self, fn: Callable, *xs: Any) -> jax.Array:
+        return fn(*xs)
+
+    # -- control flow --------------------------------------------------------
+
+    def cond(self, pred: Any, t: Callable, f: Callable, *ops: Any):
+        pred = jnp.asarray(pred)
+        _, out_t = t(self._with(self.mask & pred), *ops)
+        _, out_f = f(self._with(self.mask & ~pred), *ops)
+        out = jax.tree_util.tree_map(lambda a, b: jnp.where(pred, a, b),
+                                     out_t, out_f)
+        return self, out
+
+    def while_loop(self, c: Callable, b: Callable, init: Any):
+        init = jax.tree_util.tree_map(
+            lambda x: jnp.broadcast_to(jnp.asarray(x), (1, LANES)), init)
+
+        def live(mask, carry):
+            # an int32 carry: Mosaic keeps no boolean vector across a loop
+            return i32(mask & c(self._with(mask), carry))
+
+        def cond(loop):
+            return jnp.max(loop[0]) > 0
+
+        def body(loop):
+            on, trips, carry = loop
+            on = on != 0
+            _, new = b(self._with(on), carry)
+            carry = jax.tree_util.tree_map(
+                lambda n, o: jnp.where(on, n, o), new, carry)
+            return live(on, carry), trips + 1, carry
+
+        _, trips, out = lax.while_loop(
+            cond, body, (live(self.mask, init), jnp.zeros((), jnp.int32),
+                         init))
+        return self._with(self.mask, trips), out

@@ -209,15 +209,55 @@ def test_device_resident_classification():
         classify_inflight(keys, res.hits[:, 0], 6)
 
 
-def test_event_sim_kernel_matches_twin():
-    """The event-sim kernel body (interpreter) == its compiled twin."""
-    net = lru_network(disk_us=100.0)
-    p = np.array([0.5, 0.9])
-    twin = simulate_grid_pallas(net, p, n_requests=300, seeds=(0,))
-    kern = simulate_grid_pallas(net, p, n_requests=300, seeds=(0,),
+def _engine_outputs(monkeypatch, name):
+    """Record what ``event_sim.<name>`` returns while it stays in use."""
+    from repro.kernels import event_sim
+
+    outs = []
+    real = getattr(event_sim, name)
+
+    def keep(*a, **kw):
+        outs.append(real(*a, **kw))
+        return outs[-1]
+
+    monkeypatch.setattr(event_sim, name, keep)
+    return outs
+
+
+@pytest.mark.parametrize("net_kw,p,seeds", [
+    (dict(disk_us=100.0), [0.5, 0.9], (0,)),
+    (dict(disk_us=100.0), [0.9], (3,)),                      # one lane
+    (dict(disk_us=100.0), [0.6, 0.8], (0, 1)),               # 2 p x 2 seeds
+    (dict(disk_us=100.0), [0.5, 0.99], (2,)),                # lanes end apart
+    (dict(disk_us=100.0), np.linspace(0.5, 0.99, 10), range(13)),  # 2 blocks
+    (dict(disk_us=20.0, mpl=12, disk_servers=2), [0.3, 0.7], (0, 1)),
+], ids=["2p", "1lane", "2p2s", "far_apart", "130lanes", "disk_servers"])
+def test_event_sim_kernel_matches_twin(monkeypatch, net_kw, p, seeds):
+    """The event-sim kernel body (interpreter) == its compiled twin, lane
+    for lane: throughput, completions, events and measured time; the
+    lane-packed loop's trip count per 128-lane block is its longest
+    lane's event count."""
+    net = lru_network(**net_kw)
+    seeds = tuple(seeds)
+    twin_out = _engine_outputs(monkeypatch, "_twin_grid")
+    kern_out = _engine_outputs(monkeypatch, "pallas_grid")
+    twin = simulate_grid_pallas(net, p, n_requests=300, seeds=seeds)
+    kern = simulate_grid_pallas(net, p, n_requests=300, seeds=seeds,
                                 interpret=True)
     np.testing.assert_array_equal(twin.throughput, kern.throughput)
     np.testing.assert_array_equal(twin.p_hit, kern.p_hit)
+    (t_out,), (k_out,) = twin_out, kern_out
+    lanes = len(p) * len(seeds)
+    for name, a, b in zip(("x", "completed", "events", "t_measured_us"),
+                          t_out[:4], k_out[:4]):
+        assert a.shape == b.shape == (lanes,), name
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=name)
+    events = np.asarray(k_out[2])
+    steps = np.asarray(k_out[5])
+    assert steps.shape == (-(-lanes // 128),)
+    for blk, n in enumerate(steps):
+        assert n == events[blk * 128:(blk + 1) * 128].max()
 
 
 def test_event_sim_traced_kernel_matches_twin():

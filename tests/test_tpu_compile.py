@@ -72,8 +72,11 @@ def test_replay_kernel_compiles(one_chip, policy):
     assert compiled.memory_analysis() is not None
 
 
-@pytest.mark.parametrize("trace_cap", [0, 128])
-def test_event_kernel_compiles(one_chip, trace_cap):
+@pytest.mark.parametrize("trace_cap,lanes", [
+    (0, SIM_LANES), (128, SIM_LANES), (0, 200)], ids=["0", "128", "0-200"])
+def test_event_kernel_compiles(one_chip, trace_cap, lanes):
+    """Without tracing the lane-packed kernel (200 lanes: two 128-lane
+    blocks, the second padded), with it one lane per grid cell."""
     net = lru_network(disk_us=100.0)
     assert net.mpl == 72
     spec = compile_network(net, 0.9)
@@ -84,14 +87,14 @@ def test_event_kernel_compiles(one_chip, trace_cap):
         event_sim.BRANCH_CUM: spec.branch_cum, event_sim.VISITS: spec.visits,
         event_sim.SERVERS: spec.servers,
     }
-    tabs = {k: _sds((SIM_LANES, np.asarray(a).size), np.asarray(a).dtype,
+    tabs = {k: _sds((lanes, np.asarray(a).size), np.asarray(a).dtype,
                     one_chip) for k, a in tables.items()}
-    bmiss = (_sds((SIM_LANES, spec.branch_cum.shape[0]), jnp.int32, one_chip)
+    bmiss = (_sds((lanes, spec.branch_cum.shape[0]), jnp.int32, one_chip)
              if trace_cap else None)
     n_req = 200_000
     route_len = int(spec.visits.shape[-1])
     compiled = event_sim.pallas_grid.lower(
-        tabs, _sds((SIM_LANES,), jnp.int32, one_chip), bmiss,
+        tabs, _sds((lanes,), jnp.int32, one_chip), bmiss,
         n_requests=n_req, warmup=n_req // 4, mpl=net.mpl,
         max_events=n_req * (route_len + 2) * 3, route_len=route_len,
         trace_cap=trace_cap).compile()
