@@ -2,9 +2,10 @@
 
 :func:`compile_network` freezes a :class:`repro.core.queueing.ClosedNetwork`
 at one hit ratio into flat arrays (:class:`SimSpec`) that an event loop can
-index with traced station ids; :func:`stack_specs` stacks a grid of them
-for vmap.  The layer lives below the engines so that both the threefry
-scan simulator (:mod:`repro.core.simulator`) and the pallas kernel engine
+index with traced station ids (:func:`network_tables`: the same arrays in
+numpy, on the host); :func:`stack_specs` stacks a grid of them for vmap.
+The layer lives below the engines so that both the threefry scan
+simulator (:mod:`repro.core.simulator`) and the pallas kernel engine
 (:mod:`repro.kernels.event_sim`) can import it without the kernels package
 and the core package importing each other.
 
@@ -62,6 +63,12 @@ def _bounded_pareto_mean(alpha: float, lo: float, hi: float) -> float:
 
 def compile_network(net: ClosedNetwork, p_hit: float) -> SimSpec:
     """Freeze a network at a given hit ratio into simulator arrays."""
+    spec = network_tables(net, p_hit)
+    return SimSpec(*map(jnp.asarray, spec[:-1]), mpl=spec.mpl)
+
+
+def network_tables(net: ClosedNetwork, p_hit: float) -> SimSpec:
+    """:func:`compile_network`'s arrays as numpy arrays, left on the host."""
     names = [s.name for s in net.stations]
     idx = {n: i for i, n in enumerate(names)}
     K = len(names)
@@ -113,14 +120,14 @@ def compile_network(net: ClosedNetwork, p_hit: float) -> SimSpec:
             rank += 1
 
     return SimSpec(
-        is_queue=jnp.asarray(is_queue),
-        svc_ns=jnp.asarray(svc_ns),
-        dist_id=jnp.asarray(dist_id),
-        dist_params=jnp.asarray(dist_params),
-        branch_cum=jnp.asarray(branch_cum),
-        visits=jnp.asarray(visits),
-        servers=jnp.asarray(servers),
-        disk_rank=jnp.asarray(disk_rank),
+        is_queue=is_queue,
+        svc_ns=svc_ns,
+        dist_id=dist_id,
+        dist_params=dist_params,
+        branch_cum=branch_cum,
+        visits=visits,
+        servers=servers,
+        disk_rank=disk_rank,
         mpl=net.mpl,
     )
 

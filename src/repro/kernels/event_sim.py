@@ -50,8 +50,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.simspec import (BIG_SEQ, INF_NS, SimResult, compile_network,
-                                stack_specs)
+from repro.core.simspec import (BIG_SEQ, INF_NS, SimResult, SimSpec,
+                                network_tables)
 from repro.kernels import on_tpu
 from repro.indexed_state import ArrayState
 from repro.kernels.state import LANES, LaneRefState, RefState, vmem_rows
@@ -557,22 +557,24 @@ def simulate_grid_pallas(net, p_hits, n_requests: int = 40_000,
     specs = []
     for p in p_hits:
         with spans.span("sim.compile_network"):
-            specs.append(compile_network(net, float(p)))
+            specs.append(network_tables(net, float(p)))
     warmup = int(n_requests * warmup_frac)
     n_p, n_s = len(p_hits), len(seeds)
     trace = int(trace)
 
     def tile(a):
-        return jnp.concatenate([a] * n_s, axis=0) if n_s > 1 else a
+        return np.concatenate([a] * n_s, axis=0) if n_s > 1 else a
 
     with spans.span("sim.tables"):
-        spec = stack_specs(specs)
+        # numpy on the host: the jitted launch moves each table in one copy
+        spec = SimSpec(*map(np.stack, zip(*(s[:-1] for s in specs))),
+                       mpl=net.mpl)
         max_events = int(n_requests * (spec.visits.shape[-1] + 2) * 3)
         # the closed-loop non-coalescing kernel never touches the MSHR
         # machinery: drop disk_rank and the static mpl
         n_l = int(spec.visits.shape[-1])
         spec_tabs = {
-            IS_QUEUE: tile(spec.is_queue.astype(jnp.int32)),
+            IS_QUEUE: tile(spec.is_queue.astype(np.int32)),
             SVC_NS: tile(spec.svc_ns),
             DIST_ID: tile(spec.dist_id),
             DIST_PAR: tile(spec.dist_params.reshape(n_p, -1)),
@@ -580,9 +582,9 @@ def simulate_grid_pallas(net, p_hits, n_requests: int = 40_000,
             VISITS: tile(spec.visits.reshape(n_p, -1)),
             SERVERS: tile(spec.servers),
         }
-        seed_v = jnp.concatenate(
-            [jnp.full((n_p,), s, jnp.int32) * 1000
-             + jnp.arange(n_p, dtype=jnp.int32) for s in seeds]
+        seed_v = np.concatenate(
+            [np.full((n_p,), s, np.int32) * 1000
+             + np.arange(n_p, dtype=np.int32) for s in seeds]
         )
         bmiss_v = None
         if trace:
@@ -590,12 +592,10 @@ def simulate_grid_pallas(net, p_hits, n_requests: int = 40_000,
             # spec tables carry no disk_rank): a branch whose route touches
             # a backing store is a miss, anything else a hit (the pallas
             # engine is closed-loop non-coalescing — no delayed hits).
-            vis = np.asarray(specs[0].visits)
-            dr = np.asarray(specs[0].disk_rank)
+            vis, dr = specs[0].visits, specs[0].disk_rank
             bmiss = ((dr[np.maximum(vis, 0)] >= 0) & (vis >= 0)).any(axis=1)
-            bmiss_v = jnp.asarray(
-                np.broadcast_to(bmiss, (n_p * n_s, bmiss.shape[0])),
-                jnp.int32)
+            bmiss_v = np.broadcast_to(
+                bmiss, (n_p * n_s, bmiss.shape[0])).astype(np.int32)
 
     kw = dict(n_requests=n_requests, warmup=warmup, mpl=net.mpl,
               max_events=max_events, route_len=n_l, trace_cap=trace)

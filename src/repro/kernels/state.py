@@ -1,8 +1,10 @@
-"""Pallas-ref implementation of the indexed-state interface.
+"""Pallas-ref implementations of the indexed-state interface.
 
 :class:`RefState` runs the step code written against
 :mod:`repro.indexed_state` inside the kernel bodies, with in-place
-loads and stores of the touched slots only.
+loads and stores of the touched slots only; :class:`LaneRefState` runs it
+for up to 128 lanes at once, one per vector lane (its own docstring gives
+that layout).
 
 Kernel layout (:class:`RefState`): a table is either an SMEM ref (scalar
 reads and writes only; 1-D, or ``(1, n)`` for a per-lane block) or a VMEM
